@@ -7,20 +7,14 @@ then report the measured reduction plus the closed-form factor at both our
 and the paper's truncation levels."""
 from __future__ import annotations
 
-import os
-import subprocess
-import sys
 import textwrap
+
+from benchmarks.cpu_child import run_cpu_script
 
 
 def _measure_subprocess():
-    src = os.path.join(os.path.dirname(__file__), "..", "src")
     script = textwrap.dedent(
         """
-        import os
-        os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-        import sys
-        sys.path.insert(0, %r)
         import json
         import jax, jax.numpy as jnp
         from repro.core import FNOConfig, init_params, make_dist_forward
@@ -40,16 +34,8 @@ def _measure_subprocess():
             out[variant] = st.bytes_by_kind
         print("RESULT" + json.dumps(out))
         """
-    ) % (src,)
-    proc = subprocess.run(
-        [sys.executable, "-c", script], capture_output=True, text=True, timeout=900
     )
-    import json
-
-    for line in proc.stdout.splitlines():
-        if line.startswith("RESULT"):
-            return json.loads(line[len("RESULT"):])
-    raise RuntimeError(proc.stdout + proc.stderr[-2000:])
+    return run_cpu_script(script, n_devices=8)
 
 
 def closed_form_factor(grid, modes):

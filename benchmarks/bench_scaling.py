@@ -18,13 +18,14 @@ efficiency under TWO hardware models:
 """
 from __future__ import annotations
 
-import json
-import os
-import subprocess
-import sys
 import textwrap
 
-from repro.common.constants import ICI_BANDWIDTH_PER_LINK, PEAK_FLOPS_BF16
+from benchmarks.cpu_child import run_cpu_script
+from repro.common.constants import TARGET_DEVICE_KIND, chip_peaks
+
+_V5E = chip_peaks(TARGET_DEVICE_KIND)
+PEAK_FLOPS_BF16 = _V5E.flops_bf16
+ICI_BANDWIDTH_PER_LINK = _V5E.ici_bandwidth_per_link
 
 A100_PEAK_F32 = 19.5e12
 NVLINK_BW = 600e9
@@ -45,14 +46,9 @@ def _measure(p: int, mode: str, nx: int | None = None):
     shards (weak scaling: nx = 32*P unless a fixed nx is given for strong
     scaling), production width/modes; return per-device flops + collective
     bytes."""
-    src = os.path.join(os.path.dirname(__file__), "..", "src")
     px, py = _pencil_shape(p)
     script = textwrap.dedent(
         """
-        import os
-        os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=%d"
-        import sys
-        sys.path.insert(0, %r)
         import json
         import jax, jax.numpy as jnp
         from repro.core import FNOConfig, init_params, make_dist_forward, make_pipeline_forward
@@ -86,14 +82,8 @@ def _measure(p: int, mode: str, nx: int | None = None):
             "by_kind": coll.bytes_by_kind,
         }))
         """
-    ) % (max(p, 1), src, p, px, py, mode, nx or 0, nx or 0)
-    proc = subprocess.run(
-        [sys.executable, "-c", script], capture_output=True, text=True, timeout=1800
-    )
-    for line in proc.stdout.splitlines():
-        if line.startswith("RESULT"):
-            return json.loads(line[len("RESULT"):])
-    raise RuntimeError(proc.stdout[-1500:] + proc.stderr[-2500:])
+    ) % (p, px, py, mode, nx or 0, nx or 0)
+    return run_cpu_script(script, n_devices=max(p, 1), timeout=1800)
 
 
 def _eff(flops, coll, peak, bw, bubble=1.0):

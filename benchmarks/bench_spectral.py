@@ -22,32 +22,16 @@ from __future__ import annotations
 
 import json
 import os
-import subprocess
-import sys
 import textwrap
 
-_SRC = os.path.join(os.path.dirname(__file__), "..", "src")
+from benchmarks.cpu_child import run_cpu_script
+
 _OUT = os.path.join(os.path.dirname(__file__), "..", "artifacts", "bench")
-
-
-def _run_script(script: str, timeout: int = 900) -> dict:
-    proc = subprocess.run(
-        [sys.executable, "-c", script], capture_output=True, text=True,
-        timeout=timeout,
-    )
-    for line in proc.stdout.splitlines():
-        if line.startswith("RESULT"):
-            return json.loads(line[len("RESULT"):])
-    raise RuntimeError(proc.stdout + proc.stderr[-2000:])
 
 
 def _toy_subprocess() -> dict:
     script = textwrap.dedent(
         """
-        import os
-        os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-        import sys
-        sys.path.insert(0, %r)
         import dataclasses, json, time
         import jax, jax.numpy as jnp
         from repro.core import FNOConfig, init_params, make_dist_forward
@@ -126,8 +110,8 @@ def _toy_subprocess() -> dict:
         out["toy_1d_chunking"] = chunk_rows
         print("RESULT" + json.dumps(out))
         """
-    ) % (_SRC,)
-    return _run_script(script)
+    )
+    return run_cpu_script(script, n_devices=8)
 
 
 def _sleipner_subprocess() -> dict:
@@ -138,10 +122,6 @@ def _sleipner_subprocess() -> dict:
     # collective bytes scale linearly in n_blocks, recorded in the output.
     script = textwrap.dedent(
         """
-        import os
-        os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=32"
-        import sys
-        sys.path.insert(0, %r)
         import dataclasses, json
         import jax, jax.numpy as jnp
         from repro.configs.fno_sleipner_2d import CONFIG, MODEL_AXES, PENCIL_SHAPE
@@ -161,7 +141,7 @@ def _sleipner_subprocess() -> dict:
                                     model_axis=MODEL_AXES)
             st = ha.collect_collectives(
                 jax.jit(fwd).lower(params, x).compile().as_text(), 32)
-            out["chunks_%%d" %% chunks] = {
+            out["chunks_%d" % chunks] = {
                 "a2a_count": st.count_by_kind.get("all-to-all", 0),
                 "a2a_bytes": st.bytes_by_kind.get("all-to-all", 0.0),
                 "total_coll_bytes": st.total_bytes,
@@ -169,8 +149,8 @@ def _sleipner_subprocess() -> dict:
             }
         print("RESULT" + json.dumps(out))
         """
-    ) % (_SRC,)
-    return _run_script(script, timeout=1800)
+    )
+    return run_cpu_script(script, n_devices=32, timeout=1800)
 
 
 def run():

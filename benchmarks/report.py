@@ -8,7 +8,6 @@ import json
 import os
 
 from benchmarks import roofline
-from repro.common.constants import HBM_BYTES_PER_CHIP
 
 
 def dryrun_table(rows):

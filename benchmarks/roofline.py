@@ -15,12 +15,10 @@ import json
 import os
 from typing import List, Optional
 
-from repro.common.constants import (
-    HBM_BANDWIDTH,
-    HBM_BYTES_PER_CHIP,
-    ICI_BANDWIDTH_PER_LINK,
-    PEAK_FLOPS_BF16,
-)
+from repro.common.constants import TARGET_DEVICE_KIND, chip_peaks
+
+# the analytic model is of the target chip, whatever compiled the HLO
+PEAKS = chip_peaks(TARGET_DEVICE_KIND)
 
 ART_DIR = os.path.join(os.path.dirname(__file__), "..", "artifacts", "dryrun")
 
@@ -44,14 +42,14 @@ def terms(d: dict) -> dict:
     bytes_dev = max(d.get("hlo_bytes_est", 0.0), d.get("hlo_bytes", 0.0))
     coll_dev = d["collectives"]["total_bytes"]
     overlapped = d["collectives"].get("overlapped_bytes", 0.0)
-    t_c = flops_dev / PEAK_FLOPS_BF16
-    t_m = bytes_dev / HBM_BANDWIDTH
-    t_n = coll_dev / ICI_BANDWIDTH_PER_LINK
+    t_c = flops_dev / PEAKS.flops_bf16
+    t_m = bytes_dev / PEAKS.hbm_bandwidth
+    t_n = coll_dev / PEAKS.ici_bandwidth_per_link
     dominant = max(("compute", t_c), ("memory", t_m), ("collective", t_n), key=lambda kv: kv[1])[0]
     model_flops_dev = d["model_flops"] / n_dev
     useful = model_flops_dev / flops_dev if flops_dev else 0.0
     step_time = max(t_c, t_m, t_n)  # overlap-optimistic bound
-    mfu = model_flops_dev / PEAK_FLOPS_BF16 / step_time if step_time else 0.0
+    mfu = model_flops_dev / PEAKS.flops_bf16 / step_time if step_time else 0.0
     return {
         "arch": d["arch"],
         "shape": d["shape"],
@@ -71,7 +69,7 @@ def terms(d: dict) -> dict:
         "roofline_frac": mfu,  # MODEL_FLOPS-based fraction of peak at bound
         "peak_gib": d["memory"]["peak_per_device"] / 2**30,
         "resident_gib": d["memory"].get("resident_bytes", 0) / 2**30,
-        "fits_hbm": d["memory"].get("resident_bytes", 0) <= HBM_BYTES_PER_CHIP,
+        "fits_hbm": d["memory"].get("resident_bytes", 0) <= PEAKS.hbm_bytes,
         "_file": d["_file"],
     }
 

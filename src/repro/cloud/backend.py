@@ -13,9 +13,11 @@ from __future__ import annotations
 
 import dataclasses
 import math
+import multiprocessing
 import os
 import pickle
 import random
+import sys
 import time
 from concurrent.futures import ProcessPoolExecutor, ThreadPoolExecutor
 from typing import Any, Callable, List, Optional, Sequence
@@ -71,12 +73,30 @@ def run_task(store_root: str, fn_ref: bytes, arg_refs: Sequence, task_id: int):
     }
 
 
+def _cpu_worker_init() -> None:
+    """Pin a simulation worker to the CPU before it runs any JAX op: the
+    workers stand in for the paper's CPU cloud VMs, and the accelerator
+    belongs to the parent process alone."""
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    # spawn re-imports the parent's main module before this runs; if that
+    # imported jax, the variable was read already, so set the config too
+    if "jax" in sys.modules:
+        sys.modules["jax"].config.update("jax_platforms", "cpu")
+
+
 class LocalProcessBackend:
-    """Parallel worker processes over the shared-filesystem object store."""
+    """Parallel worker processes over the shared-filesystem object store.
+
+    Workers are spawned, not forked (a fork would copy a parent's live JAX
+    runtime), and run on the CPU (``_cpu_worker_init``)."""
 
     def __init__(self, max_workers: int = 4):
         self.max_workers = max_workers
-        self._pool = ProcessPoolExecutor(max_workers=max_workers)
+        self._pool = ProcessPoolExecutor(
+            max_workers=max_workers,
+            mp_context=multiprocessing.get_context("spawn"),
+            initializer=_cpu_worker_init,
+        )
 
     def submit(self, store_root: str, fn: Callable, arg_refs: Sequence, task_id: int):
         fn_ref = pickle.dumps(fn)

@@ -14,11 +14,8 @@ from typing import Any
 
 try:
     import zstandard as zstd
-
-    _C = zstd.ZstdCompressor(level=3)
-    _D = zstd.ZstdDecompressor()
 except ImportError:  # pragma: no cover
-    _C = _D = None
+    zstd = None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -38,8 +35,8 @@ class ObjectStore:
 
     def put(self, obj: Any) -> BlobRef:
         raw = pickle.dumps(obj, protocol=pickle.HIGHEST_PROTOCOL)
-        if _C is not None:
-            raw = _C.compress(raw)
+        if zstd is not None:  # one (thread-unsafe) compressor per call
+            raw = zstd.ZstdCompressor(level=3).compress(raw)
         key = hashlib.sha1(raw).hexdigest()[:24]
         path = os.path.join(self.root, key)
         if not os.path.exists(path):  # content-addressed: dedup free
@@ -52,6 +49,6 @@ class ObjectStore:
     def get(self, ref: BlobRef) -> Any:
         with open(os.path.join(self.root, ref.key), "rb") as f:
             raw = f.read()
-        if _D is not None:
-            raw = _D.decompress(raw)
+        if zstd is not None:
+            raw = zstd.ZstdDecompressor().decompress(raw)
         return pickle.loads(raw)

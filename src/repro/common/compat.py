@@ -1,55 +1,29 @@
-"""Version-compatibility shims for the jax API surface this repo uses.
+"""The few jax API calls whose spelling has drifted, in one place.
 
-The codebase targets the modern jax API (``jax.shard_map`` with
-``check_vma``, ``jax.make_mesh(..., axis_types=...)``) but must also run on
-jax 0.4.x, where shard_map lives in ``jax.experimental.shard_map`` (with
-``check_rep``) and meshes have no axis types. Every call site goes through
-these two helpers instead of touching ``jax.*`` directly, so the drift is
-handled in exactly one place.
+Every call site goes through these helpers instead of touching ``jax.*``
+directly, so a future drift is handled here alone.
 """
 from __future__ import annotations
 
-import inspect
 from typing import Sequence
 
 import jax
 
-if hasattr(jax, "shard_map"):
-    _shard_map_impl = jax.shard_map
-else:  # jax <= 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-_SHARD_MAP_PARAMS = frozenset(inspect.signature(_shard_map_impl).parameters)
-
 
 def shard_map(f, mesh, in_specs, out_specs):
-    """``jax.shard_map`` with replication checking off, on any jax version.
+    """``jax.shard_map`` with replication checking off.
 
     The distributed FFT paths intentionally return shards whose replication
-    cannot be inferred statically, so the repo always disables the check
-    (``check_vma=False`` on modern jax, ``check_rep=False`` on 0.4.x).
+    cannot be inferred statically, so the repo always disables the check.
     """
-    kwargs = {}
-    if "check_vma" in _SHARD_MAP_PARAMS:
-        kwargs["check_vma"] = False
-    elif "check_rep" in _SHARD_MAP_PARAMS:
-        kwargs["check_rep"] = False
-    return _shard_map_impl(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
     )
 
 
 def axis_size(axis_name: str):
-    """Size of a named mesh axis, from inside shard_map, on any jax version.
-
-    ``jax.lax.axis_size`` only exists on modern jax; 0.4.x reads the size
-    off the axis environment frame instead.
-    """
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    # psum of 1 over the axis == axis size; XLA constant-folds it, so no
-    # collective is actually emitted.
-    return jax.lax.psum(1, axis_name)
+    """Size of a named mesh axis, from inside shard_map."""
+    return jax.lax.axis_size(axis_name)
 
 
 def make_global_array(shape, sharding, fetch):
@@ -61,10 +35,6 @@ def make_global_array(shape, sharding, fetch):
     (e.g. across a data-parallel axis that doesn't split the dim) reuse the
     first fetch — which is what keeps per-process reads proportional to the
     process's share of the data, not the global array.
-
-    ``jax.make_array_from_callback`` exists on every supported jax (0.4.x
-    and modern); the per-version drift is only in how indices are
-    normalized, which is handled here so call sites stay version-free.
     """
     shape = tuple(shape)
     memo = {}
@@ -81,15 +51,8 @@ def make_global_array(shape, sharding, fetch):
 
 
 def make_mesh(shape: Sequence[int], axes: Sequence[str]):
-    """``jax.make_mesh`` with explicit Auto axis types where supported.
-
-    jax >= 0.5 wants axis types spelled out (silences the sharding-in-types
-    migration warning); jax 0.4.x has neither ``axis_types`` nor
-    ``jax.sharding.AxisType``.
-    """
-    kwargs = {}
-    if "axis_types" in inspect.signature(jax.make_mesh).parameters and hasattr(
-        jax.sharding, "AxisType"
-    ):
-        kwargs["axis_types"] = (jax.sharding.AxisType.Auto,) * len(axes)
-    return jax.make_mesh(tuple(shape), tuple(axes), **kwargs)
+    """``jax.make_mesh`` with explicit Auto axis types."""
+    return jax.make_mesh(
+        tuple(shape), tuple(axes),
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+    )

@@ -1,24 +1,56 @@
-"""Hardware constants for the roofline model (TPU v5e target).
+"""Per-chip peak numbers, keyed by ``jax.devices()[0].device_kind``, and
+the production mesh layout.
 
-The container is CPU-only; these numbers parameterize the analytic roofline
-derived from AOT-compiled HLO (see launch/roofline.py). Values provided by
-the assignment brief.
+Source of every peak: Google Cloud documentation, "TPU v5e" (system
+architecture page): 197 TFLOP/s bf16, 16 GB of HBM2 at 819 GB/s, and
+1,600 Gbit/s of inter-chip interconnect per chip over 4 ICI links (2-D
+torus). A device kind that is not in the table raises: the
+roofline never divides by another chip's peaks.
 """
+from __future__ import annotations
 
-# Per-chip peak bf16 matmul throughput.
-PEAK_FLOPS_BF16 = 197e12  # FLOP/s
+import dataclasses
 
-# Per-chip HBM bandwidth.
-HBM_BANDWIDTH = 819e9  # B/s
 
-# Per-link ICI bandwidth (one direction). v5e has a 2D torus; each chip has
-# 4 links (x+/x-/y+/y-). We report the collective term against a single link
-# per the brief ("~50 GB/s/link ICI").
-ICI_BANDWIDTH_PER_LINK = 50e9  # B/s
-ICI_LINKS_PER_CHIP = 4
+@dataclasses.dataclass(frozen=True)
+class ChipPeaks:
+    flops_bf16: float        # FLOP/s, dense bf16 matmul
+    hbm_bandwidth: float     # B/s
+    hbm_bytes: int           # B of HBM per chip
+    ici_bandwidth: float     # B/s per chip, all links, one direction
+    ici_links: int
+    source: str
 
-# HBM capacity per v5e chip (for fit checks in EXPERIMENTS.md commentary).
-HBM_BYTES_PER_CHIP = 16 * 1024**3
+    @property
+    def ici_bandwidth_per_link(self) -> float:
+        return self.ici_bandwidth / self.ici_links
+
+
+PEAKS = {
+    "TPU v5 lite": ChipPeaks(
+        flops_bf16=197e12,
+        hbm_bandwidth=819e9,
+        hbm_bytes=16 * 10**9,
+        ici_bandwidth=1600e9 / 8,
+        ici_links=4,
+        source="Google Cloud documentation, 'TPU v5e'",
+    ),
+}
+
+# The chip this repository targets (what `device_kind` reads on a v5e).
+TARGET_DEVICE_KIND = "TPU v5 lite"
+
+
+def chip_peaks(device_kind: str) -> ChipPeaks:
+    """The peak table row for ``device_kind``; an unknown kind raises."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(
+            f"no peak numbers for device kind {device_kind!r}; known: "
+            f"{sorted(PEAKS)}"
+        ) from None
+
 
 # Production mesh shape (per pod).
 POD_MESH_SHAPE = (16, 16)

@@ -51,6 +51,51 @@ def get_fno(name: str):
     return mod.CONFIG, mod.SHAPES
 
 
+# FNOConfig fields a named config may override (architecture and sizing;
+# the kernel path and comm chunking have their own launcher flags).
+FNO_OVERRIDE_KEYS = (
+    "grid", "modes", "width", "in_channels", "out_channels", "n_blocks",
+    "decoder_dim",
+)
+_FNO_TUPLE_KEYS = ("grid", "modes")
+
+
+def parse_fno_overrides(items) -> dict:
+    """``["grid=64,16,24,88", "width=40"]`` -> ``{"grid": (64, 16, 24, 88),
+    "width": 40}``; raises ValueError on an unknown key or a malformed
+    value."""
+    out = {}
+    for item in items:
+        key, sep, val = item.partition("=")
+        if not sep or key not in FNO_OVERRIDE_KEYS:
+            raise ValueError(
+                f"override {item!r}: expected KEY=VALUE with KEY one of "
+                f"{FNO_OVERRIDE_KEYS}"
+            )
+        try:
+            nums = tuple(int(v) for v in val.split(","))
+        except ValueError:
+            raise ValueError(f"override {item!r}: not integers") from None
+        want = 4 if key in _FNO_TUPLE_KEYS else 1
+        if len(nums) != want:
+            raise ValueError(f"override {item!r}: {key} takes {want} value(s)")
+        out[key] = nums if key in _FNO_TUPLE_KEYS else nums[0]
+    return out
+
+
+def fno_with_overrides(name: str, overrides: dict):
+    """The named FNO config with ``overrides`` (FNO_OVERRIDE_KEYS fields,
+    e.g. as recorded in a checkpoint's fno_config.json) replaced."""
+    unknown = sorted(set(overrides) - set(FNO_OVERRIDE_KEYS))
+    if unknown:
+        raise ValueError(f"unknown FNO override key(s) {unknown}")
+    cfg, _ = get_fno(name)
+    return dataclasses.replace(cfg, **{
+        k: tuple(int(x) for x in v) if k in _FNO_TUPLE_KEYS else int(v)
+        for k, v in overrides.items()
+    })
+
+
 def get_fno_model_axes(name: str):
     """Model-parallel layout for an FNO config: (model_axis, pencil_shape).
 

@@ -50,7 +50,7 @@ class FNOConfig:
     use_pallas: bool = False
     # Channel-chunk the distributed FFT pipelines so each chunk's
     # all-to-all overlaps the next chunk's local FFTs (bit-identical; >1
-    # only helps under a latency-hiding scheduler, see launch.devices).
+    # only helps under a latency-hiding scheduler; unmeasured on a TPU).
     comm_chunks: int = 1
     remat: bool = True        # checkpoint each FNO block (A100-80GB -> v5e-16GB)
 
@@ -206,9 +206,14 @@ def _block_weights(blk: dict):
     return (blk["w_spec_re"], blk["w_spec_im"])
 
 
+# The model computes in float32: on a TPU a matmul's default precision is
+# one bf16 pass, so every contraction asks for full float32 explicitly.
+F32 = jax.lax.Precision.HIGHEST
+
+
 def _conv1x1(x: jax.Array, w: jax.Array, b: Optional[jax.Array]) -> jax.Array:
     """Channel-mixing 1x1 conv on [b, c, x, y, z, t]."""
-    y = jnp.einsum("bixyzt,io->boxyzt", x, w.astype(x.dtype))
+    y = jnp.einsum("bixyzt,io->boxyzt", x, w.astype(x.dtype), precision=F32)
     if b is not None:
         y = y + b.astype(x.dtype)[None, :, None, None, None, None]
     return y
@@ -238,7 +243,7 @@ def encoder_prelift(params: dict, x: jax.Array, cfg: FNOConfig, channels=None) -
     if channels is not None:
         w = w[channels]
     x = x.astype(cfg.dtype)
-    return jnp.einsum("bixyzt,io->boxyzt", x, w.astype(x.dtype))
+    return jnp.einsum("bixyzt,io->boxyzt", x, w.astype(x.dtype), precision=F32)
 
 
 def _encoder_from_prelift(params: dict, pre: jax.Array, cfg: FNOConfig) -> jax.Array:

@@ -61,10 +61,9 @@ def repartition_chunked(
     first and permuting per-slice lands every element at the same place
     with the same value. What changes is the schedule: the per-chunk
     collectives are independent of each other, so a latency-hiding
-    scheduler (see ``launch.devices.OVERLAP_XLA_FLAGS``) can fly chunk
-    i's wire transfer while chunk i+1's producer (the local FFT work
-    feeding this repartition) is still computing — the MPI-overlap
-    recipe of Totounferoush et al., expressed at the XLA level.
+    scheduler can fly chunk i's wire transfer while chunk i+1's producer
+    (the local FFT work feeding this repartition) is still computing — the
+    MPI-overlap recipe of Totounferoush et al., expressed at the XLA level.
 
     ``chunks`` is clamped to the ``chunk_dim`` extent; chunk sizes may be
     uneven (no divisibility requirement).

@@ -40,14 +40,15 @@ READ_POOL_WORKERS = 8
 try:
     import zstandard as zstd
 
-    _C = zstd.ZstdCompressor(level=3)
-    _D = zstd.ZstdDecompressor()
+    # a (de)compressor object is not thread-safe and the read pool, the
+    # loader's prefetch thread and thread-backend datagen all call these:
+    # one object per call
 
     def _compress(b):
-        return _C.compress(b)
+        return zstd.ZstdCompressor(level=3).compress(b)
 
     def _decompress(b):
-        return _D.decompress(b)
+        return zstd.ZstdDecompressor().decompress(b)
 
 except ImportError:  # pragma: no cover
     def _compress(b):

@@ -12,6 +12,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.interpret import resolve_interpret
 from repro.kernels.flash_attention.kernel import flash_attention_pallas
 from repro.kernels.flash_attention.ref import attention_ref
 
@@ -33,8 +34,7 @@ def flash_attention(
     if not use_pallas:
         return attention_chunked(q, k, v, causal=causal, scale=scale, chunk_k=chunk_k)
 
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     sq, sk = q.shape[2], k.shape[2]
     pad_q = (-sq) % block_q
     pad_k = (-sk) % block_k

@@ -4,6 +4,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.interpret import resolve_interpret
 from repro.kernels.rmsnorm.kernel import rmsnorm_pallas
 from repro.kernels.rmsnorm.ref import rmsnorm_ref
 
@@ -19,8 +20,7 @@ def rmsnorm(
 ) -> jax.Array:
     if not use_pallas:
         return rmsnorm_ref(x, w, eps)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     shape = x.shape
     d = shape[-1]
     rows = x.size // d

@@ -29,14 +29,15 @@ Two kernel families live here:
    (S), per-mode channel mix (W·), and zero-padding (S^T) — into one pass.
    The unfused XLA pipeline materializes truncate -> mix -> pad as three
    HBM round trips of the mode tensor; here the grid walks the OUTPUT
-   spatial positions (block size 1 along each to-be-truncated dim, so any
-   element offset is a legal block index and no divisibility constraint
-   arises), the weight BlockSpec gathers the matching kept-mode plane via
-   a computed index map, and non-kept rows are masked to zero in-register
-   — every operand streams from HBM exactly once. The weight planes arrive
-   UNFLATTENED (same [ci, co, kx, ky, kz, kt] layout as ``w_spec``), which
-   is what lets the ops-level weight-plane cache reuse one layout across
-   every block call and every serving step.
+   (x, y) positions (block size 1 along those leading dims, so any element
+   offset is a legal block index), every block spans the full trailing
+   (z, t) extents (the TPU's (8, 128) tiling rule), the weight BlockSpec
+   gathers the matching kept-mode slab via a computed index map, and the
+   kept z/t modes are gathered in-register — every operand streams from
+   HBM exactly once. The weight planes arrive UNFLATTENED (same
+   [ci, co, kx, ky, kz, kt] layout as ``w_spec``), which is what lets the
+   ops-level weight-plane cache reuse one layout across every block call
+   and every serving step.
 
 Interpret-mode note: each grid step costs interpreter overhead (~ms), so
 keep grids small on CPU (tests use <= a few hundred steps); on TPU the
@@ -49,16 +50,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-
-def default_interpret() -> bool:
-    """Backend-sniffed interpret default: compiled on TPU, interpreter
-    elsewhere (CPU/GPU have no Pallas-TPU lowering)."""
-    return jax.default_backend() != "tpu"
-
-
-def _resolve_interpret(interpret) -> bool:
-    return default_interpret() if interpret is None else bool(interpret)
+from repro.kernels.interpret import resolve_interpret
 
 
 # ---------------------------------------------------------------------------
@@ -114,7 +108,7 @@ def spectral_apply_pallas(
     elsewhere) — a direct caller on TPU gets the real kernel, matching the
     ops.py wrapper's default.
     """
-    interpret = _resolve_interpret(interpret)
+    interpret = resolve_interpret(interpret)
     k, b, ci = xr.shape
     co = wr.shape[-1]
     assert k % block_k == 0, (k, block_k)
@@ -148,7 +142,7 @@ def spectral_dw_pallas(
 ):
     """Weight cotangent of the flattened mix: xr/xi [K,B,CI], gr/gi
     [K,B,CO] -> wr_bar/wi_bar [K,CI,CO]. Same tiling as the forward."""
-    interpret = _resolve_interpret(interpret)
+    interpret = resolve_interpret(interpret)
     k, b, ci = xr.shape
     co = gr.shape[-1]
     assert k % block_k == 0, (k, block_k)
@@ -180,6 +174,11 @@ def spectral_dw_pallas(
 # already truncated upstream (kept extent == input extent == output
 # extent). The trailing time dim is rFFT-style: the kernel always reads
 # bins [0:KT] and zero-pads the output tail up to ``t_out``.
+#
+# TPU layout: every block spans the FULL trailing (z, t) extents of its
+# array, so the (8, 128) tiling rule holds for any mode count. The grid
+# walks (x, y) positions and output-channel blocks; x/y truncation happens
+# in the index maps, z/t truncation in-register (``_z_slabs``).
 # ---------------------------------------------------------------------------
 
 def _validate_fused(x_shape, w_shape, trunc, t_out):
@@ -213,6 +212,33 @@ def _kept_index(i, n, m, k_max):
     return jnp.clip(jnp.where(i < m, i, i - (n - 2 * m)), 0, k_max - 1)
 
 
+def _full_index(kd, n, m):
+    """Kept-mode index -> full-spectrum position (inverse of _kept_index
+    restricted to kept rows): [:m] identity, [m:2m] -> [n-m:]."""
+    return jnp.where(kd < m, kd, n - 2 * m + kd)
+
+
+def _z_slabs(e3, k3, n):
+    """(spectrum row, weight row, rows) runs pairing z rows of the spectrum
+    with kept-mode weight rows: one run when z arrives pre-truncated, the
+    [:m] and [N-m:] runs when it is the full spectrum."""
+    if n is None:
+        return ((0, 0, k3),)
+    m = k3 // 2
+    return ((0, 0, m), (e3 - m, m, m))
+
+
+def _co_block(co: int) -> int:
+    """Output channels per grid step: bounds the weight block's VMEM."""
+    return next(c for c in (8, 4, 2, 1) if co % c == 0)
+
+
+def _compiler_params(interpret: bool):
+    # every block is double-buffered; the (z, t) tiles pad to (8, 128), so
+    # raise the scoped VMEM limit above the 16 MiB default (v5e has 128 MiB)
+    return None if interpret else pltpu.CompilerParams(vmem_limit_bytes=96 << 20)
+
+
 @functools.partial(jax.jit, static_argnames=("trunc", "t_out", "interpret"))
 def spectral_fused_pallas(
     xr: jax.Array,
@@ -228,61 +254,68 @@ def spectral_fused_pallas(
     the spectrum; wr/wi [CI,CO,K1,K2,K3,KT] planes of the kept-mode
     weights (natural w_spec layout) -> yr/yi [B,CO,E1,E2,E3,t_out or KT].
 
-    Each grid step (one output x/y/z position) streams one [B,CI,KT] input
-    pencil and one [CI,CO,KT] weight plane, does the 4-real-matmul complex
-    mix, masks non-kept positions to zero, and writes the padded output —
-    truncate, mix and pad in a single HBM pass.
+    Each grid step (one output x/y position, one output-channel block)
+    streams one [B,CI,E3,Tin] input slab and one [CI,cb,K3,KT] weight
+    slab, does the complex mix over ci on the kept (z, t) modes, and writes
+    the zero-padded output slab — truncate, mix and pad in a single HBM
+    pass. Non-kept (x, y) positions only write zeros.
     """
-    interpret = _resolve_interpret(interpret)
+    interpret = resolve_interpret(interpret)
     trunc = tuple(trunc)
     _validate_fused(xr.shape, wr.shape, trunc, t_out)
     b, ci = xr.shape[:2]
     co = wr.shape[1]
-    e1, e2, e3 = xr.shape[2:5]
+    e1, e2, e3, tin = xr.shape[2:]
     k1, k2, k3, kt = wr.shape[2:]
     tout = kt if t_out is None else int(t_out)
-    ms = (k1 // 2, k2 // 2, k3 // 2)
-    kept_ext = (k1, k2, k3)
+    ms = (k1 // 2, k2 // 2)
+    cb = _co_block(co)
+    slabs = _z_slabs(e3, k3, trunc[2])
 
-    def w_index(i, j, k):
-        idx = []
-        for d, p in enumerate((i, j, k)):
-            if trunc[d] is None:
-                idx.append(p)
-            else:
-                idx.append(_kept_index(p, trunc[d], ms[d], kept_ext[d]))
-        return (0, 0, idx[0], idx[1], idx[2], 0)
+    def kept(d, p):
+        if trunc[d] is None:
+            return p
+        return _kept_index(p, trunc[d], ms[d], (k1, k2)[d])
 
     def kern(xr_ref, xi_ref, wr_ref, wi_ref, yr_ref, yi_ref):
+        yr_ref[...] = jnp.zeros(yr_ref.shape, jnp.float32)
+        yi_ref[...] = jnp.zeros(yi_ref.shape, jnp.float32)
         keep = jnp.bool_(True)
-        for d in range(3):
+        for d in range(2):
             if trunc[d] is not None:
                 p = pl.program_id(d)
                 keep = keep & ((p < ms[d]) | (p >= trunc[d] - ms[d]))
-        xr_ = xr_ref[...][:, :, 0, 0, 0, :]   # [B,CI,KT]
-        xi_ = xi_ref[...][:, :, 0, 0, 0, :]
-        wr_ = wr_ref[...][:, :, 0, 0, 0, :]   # [CI,CO,KT]
-        wi_ = wi_ref[...][:, :, 0, 0, 0, :]
-        # contract ci, batch t -> [KT,B,CO]
-        dn = (((1,), (0,)), ((2,), (2,)))
-        rr = jax.lax.dot_general(xr_, wr_, dn, preferred_element_type=jnp.float32)
-        ii = jax.lax.dot_general(xi_, wi_, dn, preferred_element_type=jnp.float32)
-        ri = jax.lax.dot_general(xr_, wi_, dn, preferred_element_type=jnp.float32)
-        ir = jax.lax.dot_general(xi_, wr_, dn, preferred_element_type=jnp.float32)
-        mask = jnp.where(keep, 1.0, 0.0)
-        out_r = jnp.moveaxis(rr - ii, 0, -1) * mask   # [B,CO,KT]
-        out_i = jnp.moveaxis(ri + ir, 0, -1) * mask
-        if tout > kt:  # fused S^T along t: zero tail, never materialized
-            z = jnp.zeros((b, co, tout - kt), jnp.float32)
-            out_r = jnp.concatenate([out_r, z], axis=-1)
-            out_i = jnp.concatenate([out_i, z], axis=-1)
-        yr_ref[...] = out_r[:, :, None, None, None, :]
-        yi_ref[...] = out_i[:, :, None, None, None, :]
 
-    grid = (e1, e2, e3)
-    x_spec = pl.BlockSpec((b, ci, 1, 1, 1, kt), lambda i, j, k: (0, 0, i, j, k, 0))
-    w_spec = pl.BlockSpec((ci, co, 1, 1, 1, kt), w_index)
-    y_spec = pl.BlockSpec((b, co, 1, 1, 1, tout), lambda i, j, k: (0, 0, i, j, k, 0))
+        @pl.when(keep)
+        def _mix():
+            for x0, w0, n in slabs:
+                def body(c, acc):
+                    ar, ai = acc
+                    xs = (c, 0, 0, pl.ds(x0, n), pl.ds(0, kt))
+                    a = xr_ref[(slice(None),) + xs][:, None]    # [B,1,n,KT]
+                    bi = xi_ref[(slice(None),) + xs][:, None]
+                    ws = (c, slice(None), 0, 0, pl.ds(w0, n), slice(None))
+                    u = wr_ref[ws][None]                        # [1,cb,n,KT]
+                    v = wi_ref[ws][None]
+                    return ar + (a * u - bi * v), ai + (a * v + bi * u)
+
+                z = jnp.zeros((b, cb, n, kt), jnp.float32)
+                ar, ai = jax.lax.fori_loop(0, ci, body, (z, z))
+                ys = (slice(None), slice(None), 0, 0, pl.ds(x0, n), pl.ds(0, kt))
+                yr_ref[ys] = ar
+                yi_ref[ys] = ai
+
+    grid = (e1, e2, co // cb)
+    x_spec = pl.BlockSpec(
+        (b, ci, 1, 1, e3, tin), lambda i, j, c: (0, 0, i, j, 0, 0)
+    )
+    w_spec = pl.BlockSpec(
+        (ci, cb, 1, 1, k3, kt),
+        lambda i, j, c: (0, c, kept(0, i), kept(1, j), 0, 0),
+    )
+    y_spec = pl.BlockSpec(
+        (b, cb, 1, 1, e3, tout), lambda i, j, c: (0, c, i, j, 0, 0)
+    )
     out_shape = [
         jax.ShapeDtypeStruct((b, co, e1, e2, e3, tout), jnp.float32),
         jax.ShapeDtypeStruct((b, co, e1, e2, e3, tout), jnp.float32),
@@ -293,14 +326,10 @@ def spectral_fused_pallas(
         in_specs=[x_spec, x_spec, w_spec, w_spec],
         out_specs=[y_spec, y_spec],
         out_shape=out_shape,
+        compiler_params=_compiler_params(interpret),
         interpret=interpret,
+        name="spectral_fused",
     )(xr, xi, wr, wi)
-
-
-def _full_index(kd, n, m):
-    """Kept-mode index -> full-spectrum position (inverse of _kept_index
-    restricted to kept rows): [:m] identity, [m:2m] -> [n-m:]."""
-    return jnp.where(kd < m, kd, n - 2 * m + kd)
 
 
 @functools.partial(jax.jit, static_argnames=("trunc", "kept", "interpret"))
@@ -319,47 +348,55 @@ def spectral_fused_dw(
 
     xr/xi [B,CI,E1,E2,E3,Tx], gr/gi [B,CO,E1,E2,E3,Tg] are the (possibly
     full) spectrum planes the forward consumed/produced; ``kept`` is the
-    weight mode shape (K1,K2,K3,KT). The grid walks kept coordinates only
-    — every output element is written, so no masking or padding is needed
-    — and the x/g BlockSpec index maps gather the kept full-spectrum
-    positions ([:m] and [N-m:] for truncated dims, identity otherwise).
+    weight mode shape (K1,K2,K3,KT). The grid walks kept (x, y) coordinates
+    only — every output element is written, so no masking is needed — the
+    x/g BlockSpec index maps gather the kept full-spectrum positions, and
+    the kept z rows are gathered in-register.
     """
-    interpret = _resolve_interpret(interpret)
+    interpret = resolve_interpret(interpret)
     trunc = tuple(trunc)
     k1, k2, k3, kt = kept
     b, ci = xr.shape[:2]
     co = gr.shape[1]
+    e3 = xr.shape[4]
     if xr.shape[5] < kt or gr.shape[5] < kt:
         raise ValueError(f"time bins {xr.shape[5]}/{gr.shape[5]} < kt={kt}")
-    ms = (k1 // 2, k2 // 2, k3 // 2)
+    ms = (k1 // 2, k2 // 2)
+    cb = _co_block(co)
+    slabs = _z_slabs(e3, k3, trunc[2])
 
-    def xg_index(i, j, k):
-        idx = []
-        for d, p in enumerate((i, j, k)):
-            if trunc[d] is None:
-                idx.append(p)
-            else:
-                idx.append(_full_index(p, trunc[d], ms[d]))
-        return (0, 0, idx[0], idx[1], idx[2], 0)
+    def full(d, p):
+        return p if trunc[d] is None else _full_index(p, trunc[d], ms[d])
 
     def kern(xr_ref, xi_ref, gr_ref, gi_ref, wr_ref, wi_ref):
-        xr_ = xr_ref[...][:, :, 0, 0, 0, :]   # [B,CI,KT]
-        xi_ = xi_ref[...][:, :, 0, 0, 0, :]
-        gr_ = gr_ref[...][:, :, 0, 0, 0, :]   # [B,CO,KT]
-        gi_ = gi_ref[...][:, :, 0, 0, 0, :]
-        # contract b, batch t -> [KT,CI,CO]
-        dn = (((0,), (0,)), ((2,), (2,)))
-        rr = jax.lax.dot_general(xr_, gr_, dn, preferred_element_type=jnp.float32)
-        ii = jax.lax.dot_general(xi_, gi_, dn, preferred_element_type=jnp.float32)
-        ri = jax.lax.dot_general(xr_, gi_, dn, preferred_element_type=jnp.float32)
-        ir = jax.lax.dot_general(xi_, gr_, dn, preferred_element_type=jnp.float32)
-        wr_ref[...] = jnp.moveaxis(rr - ii, 0, -1)[:, :, None, None, None, :]
-        wi_ref[...] = jnp.moveaxis(ri + ir, 0, -1)[:, :, None, None, None, :]
+        for x0, w0, n in slabs:
+            gs = (slice(None), slice(None), 0, 0, pl.ds(x0, n), pl.ds(0, kt))
+            g_r = gr_ref[gs]                                    # [B,cb,n,KT]
+            g_i = gi_ref[gs]
 
-    grid = (k1, k2, k3)
-    x_spec = pl.BlockSpec((b, ci, 1, 1, 1, kt), xg_index)
-    g_spec = pl.BlockSpec((b, co, 1, 1, 1, kt), xg_index)
-    w_spec = pl.BlockSpec((ci, co, 1, 1, 1, kt), lambda i, j, k: (0, 0, i, j, k, 0))
+            def body(c, carry):
+                xs = (slice(None), c, 0, 0, pl.ds(x0, n), pl.ds(0, kt))
+                a = xr_ref[xs][:, None]                         # [B,1,n,KT]
+                bi = xi_ref[xs][:, None]
+                ws = (c, slice(None), 0, 0, pl.ds(w0, n), slice(None))
+                wr_ref[ws] = jnp.sum(a * g_r - bi * g_i, axis=0)
+                wi_ref[ws] = jnp.sum(a * g_i + bi * g_r, axis=0)
+                return carry
+
+            jax.lax.fori_loop(0, ci, body, 0)
+
+    grid = (k1, k2, co // cb)
+    x_spec = pl.BlockSpec(
+        (b, ci, 1, 1, e3, xr.shape[5]),
+        lambda i, j, c: (0, 0, full(0, i), full(1, j), 0, 0),
+    )
+    g_spec = pl.BlockSpec(
+        (b, cb, 1, 1, e3, gr.shape[5]),
+        lambda i, j, c: (0, c, full(0, i), full(1, j), 0, 0),
+    )
+    w_spec = pl.BlockSpec(
+        (ci, cb, 1, 1, k3, kt), lambda i, j, c: (0, c, i, j, 0, 0)
+    )
     out_shape = [
         jax.ShapeDtypeStruct((ci, co, k1, k2, k3, kt), jnp.float32),
         jax.ShapeDtypeStruct((ci, co, k1, k2, k3, kt), jnp.float32),
@@ -370,5 +407,7 @@ def spectral_fused_dw(
         in_specs=[x_spec, x_spec, g_spec, g_spec],
         out_specs=[w_spec, w_spec],
         out_shape=out_shape,
+        compiler_params=_compiler_params(interpret),
         interpret=interpret,
+        name="spectral_fused_dw",
     )(xr, xi, gr, gi)

@@ -34,6 +34,7 @@ import weakref
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.interpret import resolve_interpret
 from repro.kernels.spectral_conv.kernel import (
     spectral_apply_pallas,
     spectral_dw_pallas,
@@ -89,7 +90,8 @@ def cached_weight_planes(w: jax.Array):
     _PLANE_STATS["misses"] += 1
     planes = weight_planes(w)
     try:
-        ref = weakref.ref(w, lambda _ref: _PLANE_CACHE.pop(key, None))
+        # the dict is bound now: at interpreter exit the global may be gone
+        ref = weakref.ref(w, lambda _ref, c=_PLANE_CACHE: c.pop(key, None))
     except TypeError:  # array type without weakref support: strong ref
         ref = w
     _PLANE_CACHE[key] = (ref, planes)
@@ -172,8 +174,7 @@ def spectral_apply(
     if not use_pallas:
         return spectral_apply_ref(xf, w)
 
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
 
     b, ci, *modes = xf.shape
     co = w.shape[1]
@@ -265,6 +266,9 @@ def spectral_apply_fused(
     re-combine entirely, which is the point of the plane cache.
     """
     trunc = tuple(trunc)
+    if use_pallas:
+        # resolved here, outside the jitted kernels' trace caches
+        interpret = resolve_interpret(interpret)
     if isinstance(w, tuple):
         wr, wi = w
         if not use_pallas:

@@ -19,7 +19,8 @@ def spectral_apply_ref(xf: jax.Array, w: jax.Array) -> jax.Array:
     n_modes = xf.ndim - 2
     mode_axes = "".join(chr(ord("s") + i) for i in range(n_modes))
     eq = f"bi{mode_axes},io{mode_axes}->bo{mode_axes}"
-    return jnp.einsum(eq, xf, w)
+    # full float32 (a TPU's default is one bf16 pass), like core.fno.F32
+    return jnp.einsum(eq, xf, w, precision=jax.lax.Precision.HIGHEST)
 
 
 # Local truncate/pad helpers: semantically identical to core.dfft's

@@ -23,31 +23,9 @@ def sniff_devices(argv):
     return None
 
 
-# Latency-hiding flags for communication/compute overlap: let XLA's
-# scheduler fly one channel-chunk's all-to-all (see FNOConfig.comm_chunks)
-# while the next chunk's local FFTs compute. NOTE: the classic
-# --xla_gpu_enable_async_collectives flag is deliberately ABSENT — recent
-# jaxlibs removed it (async collectives are on by default) and XLA
-# hard-crashes on unknown XLA_FLAGS entries.
-OVERLAP_XLA_FLAGS = (
-    "--xla_gpu_enable_latency_hiding_scheduler=true",
-    "--xla_gpu_enable_highest_priority_async_stream=true",
-)
-
-
-def overlap_flags() -> str:
-    """The overlap flag string, or "" when opted out via
-    REPRO_NO_OVERLAP_FLAGS=1 (e.g. to A/B the scheduler's effect)."""
-    if os.environ.get("REPRO_NO_OVERLAP_FLAGS"):
-        return ""
-    return " ".join(OVERLAP_XLA_FLAGS)
-
-
 def apply_device_flag(argv) -> None:
-    """Set the XLA host-device-count flag if argv carries --devices, plus
-    the latency-hiding scheduler flags (harmless on CPU; on GPU they enable
-    the collective overlap the chunked repartition path is shaped for)."""
+    """Set the XLA host-device-count flag if argv carries --devices. It
+    only sizes the CPU backend; on a TPU the device count is the chip's."""
     n = sniff_devices(argv)
     if n is not None:
-        flags = f"--xla_force_host_platform_device_count={n} {overlap_flags()}"
-        os.environ["XLA_FLAGS"] = flags.strip()
+        os.environ["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n}"

@@ -1,10 +1,17 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# ^ MUST precede any jax import: jax locks the device count at first init.
+"""Multi-pod AOT dry-run: lower + compile every (arch x shape x mesh) cell.
+
+For each cell this produces a JSON artifact with:
+  * compiled.memory_analysis()  -> per-device bytes (proves it fits)
+  * compiled.cost_analysis()    -> HLO FLOPs / bytes accessed
+  * parsed collective traffic   -> bytes on the ICI wire per device
+  * analytic MODEL_FLOPS        -> 6·N·D (train) or 2·N·D (serve)
+EXPERIMENTS.md §Dry-run / §Roofline are generated from these artifacts.
+"""
 import argparse
 import dataclasses
 import functools
 import json
+import os
 import time
 from typing import Optional
 
@@ -32,16 +39,6 @@ from repro.models import whisper as wh_lib
 from repro.models.policy import ParallelPolicy
 from repro.train import AdamWConfig, init_opt_state, make_train_step
 from repro.train.optimizer import opt_state_specs
-
-"""Multi-pod AOT dry-run: lower + compile every (arch x shape x mesh) cell.
-
-For each cell this produces a JSON artifact with:
-  * compiled.memory_analysis()  -> per-device bytes (proves it fits)
-  * compiled.cost_analysis()    -> HLO FLOPs / bytes accessed
-  * parsed collective traffic   -> bytes on the ICI wire per device
-  * analytic MODEL_FLOPS        -> 6·N·D (train) or 2·N·D (serve)
-EXPERIMENTS.md §Dry-run / §Roofline are generated from these artifacts.
-"""
 
 
 def _safe(spec: P, shape, mesh) -> P:
@@ -376,6 +373,11 @@ def main():
     )
     ap.add_argument("--out-dir", default="artifacts/dryrun")
     args = ap.parse_args()
+
+    # 512 simulated CPU devices; read when the backend first initializes,
+    # so this must run before anything asks jax for its devices
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+    jax.config.update("jax_platforms", "cpu")
 
     if args.list:
         for kind, arch, shape in iter_cells():

@@ -17,17 +17,12 @@ from the latest on crash (--inject-fault demonstrates it), straggler
 watchdog. ``--devices N`` spawns N host devices for a real data-parallel
 mesh on CPU.
 """
-import os
-import sys
-
-# must precede any jax import (repro.launch.devices never imports jax)
-from repro.launch.devices import apply_device_flag
-
-apply_device_flag(sys.argv)
-
 import argparse
+import dataclasses
 import functools
 import json
+import os
+import sys
 import threading
 import time
 
@@ -35,15 +30,19 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.configs import get_arch, reduced
+from repro.common.compile_cache import enable_compile_cache
+from repro.configs import (
+    FNO_IDS, fno_with_overrides, get_arch, parse_fno_overrides, reduced,
+)
 from repro.core import FNOConfig, forward_and_specs, init_params, mse_loss
+from repro.launch.devices import apply_device_flag
 from repro.launch.devices import sniff_devices  # noqa: F401  (re-export)
 from repro.launch.mesh import build_fno_mesh
 from repro.models import init_lm_params, lm_loss
 from repro.models.policy import LOCAL
 from repro.train import AdamWConfig, init_opt_state, make_train_step, warmup_cosine
 from repro.train.fault import FaultInjector, run_supervised
-from repro.train.train_loop import shard_train_step
+from repro.train.train_loop import shard_train_step, train_state_shardings
 
 
 def start_online_datagen(args):
@@ -117,15 +116,76 @@ def _wait_online(path: str, err: list, timeout: float, need_stats: bool):
         time.sleep(0.05)
 
 
-def synthetic_fno_data(cfg: FNOConfig, n: int, seed: int = 0):
-    """Band-limited random fields (stand-in when no simulated store given)."""
-    key = jax.random.PRNGKey(seed)
+def synthetic_fno_data(cfg: FNOConfig, n: int, seed: int = 0,
+                       geomodel: bool = False):
+    """Band-limited random fields (stand-in when no simulated store given).
+
+    ``geomodel`` lays the inputs out as a ``datagen --geomodel`` store does:
+    channel 0 is the shared log-permeability geomodel (the static channel
+    the serving cache keys on), the others are seeded injection-well maps;
+    the target is then a transform of the well channels."""
     nx, ny, nz, nt = cfg.grid
-    k1, k2 = jax.random.split(key)
-    x = jax.random.normal(k1, (n, cfg.in_channels, nx, ny, nz, nt), jnp.float32)
+    if geomodel:
+        from repro.data.pde.two_phase import TwoPhaseConfig, random_well_mask
+        from repro.launch.datagen import geomodel_channel
+
+        if cfg.in_channels < 2:
+            raise SystemExit("--geomodel needs in_channels >= 2")
+        sim_cfg = TwoPhaseConfig(grid=(nx, ny, nz))
+        geo = geomodel_channel((nx, ny, nz), nt)
+        wells = np.stack([
+            np.repeat(random_well_mask(sim_cfg, 2, seed + i)[None, ..., None],
+                      cfg.in_channels - 1, axis=0).repeat(nt, axis=-1)
+            for i in range(n)
+        ])
+        x = np.concatenate([np.repeat(geo[None], n, axis=0), wells], axis=1)
+        src = jnp.asarray(wells)
+    else:
+        k1, _ = jax.random.split(jax.random.PRNGKey(seed))
+        x = src = jax.random.normal(
+            k1, (n, cfg.in_channels, nx, ny, nz, nt), jnp.float32
+        )
     # target: smoothed nonlinear transform (learnable mapping)
-    y = jnp.tanh(jnp.roll(x, 1, axis=2) + 0.5 * jnp.roll(x, 2, axis=3)) * 0.5
-    return np.asarray(x), np.asarray(y[:, : cfg.out_channels])
+    y = jnp.tanh(jnp.roll(src, 1, axis=2) + 0.5 * jnp.roll(src, 2, axis=3)) * 0.5
+    return np.asarray(x, np.float32), np.asarray(y[:, : cfg.out_channels])
+
+
+def fno_config_from_args(args, store_grid=None, store_channels=None):
+    """The run's FNOConfig: a named config with explicit overrides
+    (``--config``/``--override``), or the small ``--grid``/``--width``
+    model. A store, when given, must agree with the named config."""
+    if args.config is None:
+        grid = tuple(store_grid or args.grid or (16, 16, 8, 8))
+        in_ch, out_ch = store_channels or (1, 1)
+        return FNOConfig(
+            grid=grid,
+            modes=tuple(max(2, g // 4) for g in grid),
+            width=args.width or 8,
+            in_channels=in_ch,
+            out_channels=out_ch,
+            n_blocks=4,
+            decoder_dim=32,
+            use_pallas=args.use_pallas,
+            comm_chunks=args.comm_chunks,
+        )
+    if args.grid is not None or args.width is not None:
+        raise SystemExit(
+            "--config takes its sizes from the named config; change them "
+            "with --override grid=... / width=..."
+        )
+    cfg = fno_with_overrides(args.config, args.overrides)
+    if store_grid is not None and (
+        tuple(store_grid) != cfg.grid
+        or tuple(store_channels) != (cfg.in_channels, cfg.out_channels)
+    ):
+        raise SystemExit(
+            f"store grid {tuple(store_grid)} / channels {store_channels} "
+            f"disagree with {args.config} {cfg.grid} / "
+            f"{(cfg.in_channels, cfg.out_channels)}"
+        )
+    return dataclasses.replace(
+        cfg, use_pallas=args.use_pallas, comm_chunks=args.comm_chunks
+    )
 
 
 def write_fno_serving_config(ckpt_dir: str, cfg: FNOConfig, args, x_src, y_src,
@@ -142,6 +202,12 @@ def write_fno_serving_config(ckpt_dir: str, cfg: FNOConfig, args, x_src, y_src,
 
     os.makedirs(ckpt_dir, exist_ok=True)
     payload = {
+        # the named config and its overrides, when the run started from one
+        "config": args.config,
+        "overrides": {
+            k: list(v) if isinstance(v, tuple) else v
+            for k, v in (args.overrides or {}).items()
+        },
         "grid": list(cfg.grid),
         "modes": list(cfg.modes),
         "width": cfg.width,
@@ -163,7 +229,35 @@ def write_fno_serving_config(ckpt_dir: str, cfg: FNOConfig, args, x_src, y_src,
     os.rename(tmp, os.path.join(ckpt_dir, "fno_config.json"))
 
 
-def main():
+def compile_report(jit_step, abstract_params, cfg: FNOConfig, batch: int) -> dict:
+    """Compile the fno train step ahead of the run (the run's own call then
+    finds it in the persistent cache) and print what the device program
+    needs: compile seconds and ``memory_analysis`` bytes."""
+    xb = jax.ShapeDtypeStruct((batch, cfg.in_channels) + cfg.grid, jnp.float32)
+    yb = jax.ShapeDtypeStruct((batch, cfg.out_channels) + cfg.grid, jnp.float32)
+    t0 = time.perf_counter()
+    compiled = jit_step.lower(
+        abstract_params, jax.eval_shape(init_opt_state, abstract_params),
+        {"x": xb, "y": yb},
+    ).compile()
+    rep = {"compile_s": time.perf_counter() - t0}
+    mem = compiled.memory_analysis()
+    for k in ("argument", "output", "alias", "temp"):
+        rep[f"{k}_bytes"] = int(getattr(mem, f"{k}_size_in_bytes"))
+    rep["peak_bytes"] = int(mem.peak_memory_in_bytes)
+    print(
+        f"compile: train step {rep['compile_s']:.1f}s; compiled bytes "
+        + " ".join(f"{k} {rep[k + '_bytes'] / 1e9:.2f} GB"
+                   for k in ("argument", "output", "alias", "temp", "peak")),
+        flush=True,
+    )
+    return rep
+
+
+def main(argv=None):
+    """Train; returns the supervisor's result, whose ``info`` carries the
+    run's config and, with ``--compile-report``, the step's compile time
+    and compiled bytes."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--mode", choices=("fno", "lm"), default="fno")
     ap.add_argument("--arch", default="gemma-7b", help="lm mode: assigned arch id")
@@ -198,8 +292,24 @@ def main():
     ap.add_argument("--no-prefetch", action="store_true",
                     help="disable the loader's background prefetch thread")
     ap.add_argument("--no-shuffle", action="store_true")
-    ap.add_argument("--grid", type=int, nargs=4, default=(16, 16, 8, 8))
-    ap.add_argument("--width", type=int, default=8)
+    ap.add_argument("--grid", type=int, nargs=4, default=None,
+                    help="fno mode without --config (default 16 16 8 8)")
+    ap.add_argument("--width", type=int, default=None,
+                    help="fno mode without --config (default 8)")
+    ap.add_argument("--config", choices=FNO_IDS, default=None,
+                    help="fno mode: start from a named FNO config "
+                    "(configs.get_fno) at its published sizes")
+    ap.add_argument("--override", action="append", default=[],
+                    metavar="KEY=VALUE",
+                    help="--config: replace one config field, e.g. "
+                    "grid=64,16,24,88 or modes=24,2,2,10 (repeatable); "
+                    "recorded in fno_config.json")
+    ap.add_argument("--geomodel", action="store_true",
+                    help="fno mode, synthetic data: channel 0 is the shared "
+                    "geomodel (static serving channel), the rest well maps")
+    ap.add_argument("--compile-report", action="store_true",
+                    help="compile the train step ahead of the run and "
+                    "print its compile seconds and compiled bytes")
     ap.add_argument("--n-data", type=int, default=16)
     ap.add_argument("--devices", type=int, default=1)
     ap.add_argument("--seed", type=int, default=0)
@@ -212,7 +322,7 @@ def main():
     ap.add_argument(
         "--use-pallas", action="store_true",
         help="fno mode: fused Pallas spectral path (truncate + channel-mix "
-        "+ pad in one kernel pass; interpret mode off-TPU). Equivalence-"
+        "+ pad in one kernel pass; interpret mode on CPU). Equivalence-"
         "gated vs the unfused path; persisted into fno_config.json so "
         "serving defaults to the same path.",
     )
@@ -220,9 +330,16 @@ def main():
         "--comm-chunks", type=int, default=1,
         help="fno mode: channel-chunk the distributed FFT pipelines so "
         "each chunk's all-to-all overlaps the next chunk's FFTs "
-        "(bit-identical; needs the latency-hiding scheduler flags).",
+        "(bit-identical; the overlap needs a latency-hiding scheduler).",
     )
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    try:
+        args.overrides = parse_fno_overrides(args.override)
+    except ValueError as e:
+        raise SystemExit(f"--override: {e}") from None
+    if args.overrides and args.config is None:
+        raise SystemExit("--override needs --config")
+    enable_compile_cache()
 
     opt_cfg = AdamWConfig(
         lr=warmup_cosine(args.lr, warmup=10, total=args.steps), weight_decay=0.0
@@ -256,24 +373,18 @@ def main():
             else:
                 x_src = y_src = None
         if x_src is not None:
-            grid = tuple(x_src.shape[-4:])
-            in_ch, out_ch = x_src.shape[1], y_src.shape[1]
+            if args.geomodel:
+                raise SystemExit("--geomodel shapes synthetic data; a "
+                                 "--geomodel datagen store already has it")
+            cfg = fno_config_from_args(
+                args, x_src.shape[-4:], (x_src.shape[1], y_src.shape[1])
+            )
         else:
-            grid = tuple(args.grid)
-            in_ch = out_ch = 1
-        cfg = FNOConfig(
-            grid=grid,
-            modes=tuple(max(2, g // 4) for g in grid),
-            width=args.width,
-            in_channels=in_ch,
-            out_channels=out_ch,
-            n_blocks=4,
-            decoder_dim=32,
-            use_pallas=args.use_pallas,
-            comm_chunks=args.comm_chunks,
-        )
+            cfg = fno_config_from_args(args)
         if x_src is None:
-            x_all, y_all = synthetic_fno_data(cfg, args.n_data)
+            x_all, y_all = synthetic_fno_data(
+                cfg, args.n_data, seed=args.seed, geomodel=args.geomodel
+            )
             x_src, y_src = NdArraySource(x_all), NdArraySource(y_all)
 
         try:
@@ -380,9 +491,20 @@ def main():
         step_fn, mesh, p_specs, abstract_params, batch_specs, dp_axes=("data",)
     )
 
+    state_shardings = train_state_shardings(
+        mesh, p_specs, abstract_params, dp_axes=("data",)
+    )
+
+    @functools.partial(jax.jit, out_shardings=state_shardings)
     def init_state():
+        # built in place on the mesh: a model-parallel state may not fit
+        # whole on one device
         params = init_fn(jax.random.PRNGKey(0))
         return {"params": params, "opt": init_opt_state(params)}
+
+    info = {"config": args.config, "overrides": args.overrides}
+    if args.compile_report and args.mode == "fno":
+        info.update(compile_report(jit_step, abstract_params, cfg, args.batch))
 
     online_info = {}
 
@@ -405,6 +527,7 @@ def main():
             ckpt_dir=args.ckpt_dir,
             save_every=args.save_every,
             injector=injector,
+            shardings=state_shardings,
             async_save=True,
         )
     finally:
@@ -414,6 +537,7 @@ def main():
         dg_thread.join()  # let the simulator finish/flush before reporting
         if dg_err:
             raise RuntimeError("online datagen failed") from dg_err[0]
+    result.info.update(info)
     first = result.metrics_log[0][1]["loss"] if result.metrics_log else float("nan")
     last = result.metrics_log[-1][1]["loss"] if result.metrics_log else float("nan")
     print(
@@ -436,4 +560,6 @@ def main():
 
 
 if __name__ == "__main__":
+    # before jax starts its backend: --devices sizes the CPU backend
+    apply_device_flag(sys.argv)
     main()

@@ -345,6 +345,22 @@ class FNORunner:
             out_channels=saved["out_channels"],
             n_blocks=saved["n_blocks"],
             decoder_dim=saved["decoder_dim"],
+        )
+        if saved.get("config"):
+            # a run started from a named config: serve that config with the
+            # recorded overrides, and refuse if it no longer matches the
+            # architecture the checkpoint was trained with
+            from repro.configs import fno_with_overrides
+
+            named = fno_with_overrides(saved["config"], saved.get("overrides", {}))
+            if dataclasses.replace(named, use_pallas=False, comm_chunks=1) != cfg:
+                raise ValueError(
+                    f"{cfg_path}: {saved['config']} with overrides "
+                    f"{saved.get('overrides')} is {named}, but the checkpoint "
+                    f"was trained as {cfg}"
+                )
+        cfg = dataclasses.replace(
+            cfg,
             use_pallas=bool(
                 saved.get("use_pallas", False) if use_pallas is None
                 else use_pallas
@@ -570,6 +586,24 @@ class FNORunner:
             self._inputs[slot] = self._encode(req.x)
         self._remaining[slot] = int(req.steps)
 
+    def _served_forward(self, bucket: int):
+        """(jitted forward, zero batch args after params) for one bucket:
+        the deep split, the split, or the plain forward — whichever
+        ``step`` runs for this runner."""
+        grid = tuple(self.cfg.grid)
+        if not self.n_static:
+            return self._forward, (
+                np.zeros((bucket, self.cfg.in_channels) + grid, np.float32),
+            )
+        pre = np.zeros((bucket, self.cfg.width) + grid, np.float32)
+        xd = np.zeros(
+            (bucket, self.cfg.in_channels - self.n_static) + grid, np.float32
+        )
+        if self._forward_deep is None:
+            return self._forward_split, (pre, xd)
+        ck = np.zeros((bucket, self.cfg.width) + self.cfg.mode_shape, np.complex64)
+        return self._forward_deep, (ck, pre, xd)
+
     def warmup(self) -> float:
         """jit-compile every bucket shape up front (zero batches); returns
         seconds spent, so drivers can report compile time separately from
@@ -577,28 +611,17 @@ class FNORunner:
         import time as _time
 
         t0 = _time.perf_counter()
-        grid = tuple(self.cfg.grid)
         for b in self.buckets:
-            if self.n_static:
-                pre = np.zeros((b, self.cfg.width) + grid, np.float32)
-                xd = np.zeros(
-                    (b, self.cfg.in_channels - self.n_static) + grid, np.float32
-                )
-                if self._forward_deep is not None:
-                    ck = np.zeros(
-                        (b, self.cfg.width) + self.cfg.mode_shape, np.complex64
-                    )
-                    jax.block_until_ready(
-                        self._forward_deep(self.params, ck, pre, xd)
-                    )
-                else:
-                    jax.block_until_ready(
-                        self._forward_split(self.params, pre, xd)
-                    )
-            else:
-                xb = np.zeros((b, self.cfg.in_channels) + grid, np.float32)
-                jax.block_until_ready(self._forward(self.params, xb))
+            fwd, args = self._served_forward(b)
+            jax.block_until_ready(fwd(self.params, *args))
         return _time.perf_counter() - t0
+
+    def compiled_step(self, bucket: int):
+        """The compiled device program ``step`` runs at ``bucket``: its
+        ``as_text()`` and ``memory_analysis()`` describe what the device
+        executes (e.g. whether a Pallas kernel is in it)."""
+        fwd, args = self._served_forward(bucket)
+        return fwd.lower(self.params, *args).compile()
 
     def bucket_for(self, n_active: int) -> int:
         for b in self.buckets:

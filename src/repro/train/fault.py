@@ -60,6 +60,8 @@ class SupervisorResult:
     restores: int
     metrics_log: list
     straggler_steps: list
+    # what the caller knows about the run (config, compile stats, ...)
+    info: dict = dataclasses.field(default_factory=dict)
 
 
 def run_supervised(

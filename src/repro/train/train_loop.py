@@ -56,6 +56,29 @@ def make_train_step(
     return train_step
 
 
+def _named(mesh: Mesh, spec_tree):
+    return jax.tree.map(
+        lambda s: NamedSharding(mesh, s if isinstance(s, P) else P()),
+        spec_tree,
+        is_leaf=lambda x: isinstance(x, P),
+    )
+
+
+def train_state_shardings(
+    mesh: Mesh,
+    param_specs,
+    abstract_params,
+    *,
+    dp_axes=("data",),
+    zero1: bool = True,
+) -> dict:
+    """``{"params": ..., "opt": ...}`` shardings of the train state, as the
+    sharded step takes and returns it: build and restore the state with
+    these, so no device ever holds the whole unsharded state."""
+    opt_specs = opt_state_specs(param_specs, abstract_params, mesh, dp_axes, zero1)
+    return {"params": _named(mesh, param_specs), "opt": _named(mesh, opt_specs)}
+
+
 def shard_train_step(
     train_step: Callable,
     mesh: Mesh,
@@ -68,17 +91,11 @@ def shard_train_step(
     donate: bool = True,
 ):
     """jit the step with explicit in/out shardings (params/opt donated)."""
-    opt_specs = opt_state_specs(param_specs, abstract_params, mesh, dp_axes, zero1)
-
-    def ns(tree):
-        return jax.tree.map(
-            lambda s: NamedSharding(mesh, s if isinstance(s, P) else P()),
-            tree,
-            is_leaf=lambda x: isinstance(x, P),
-        )
-
-    in_shardings = (ns(param_specs), ns(opt_specs), ns(batch_specs))
-    out_shardings = (ns(param_specs), ns(opt_specs), None)
+    state = train_state_shardings(
+        mesh, param_specs, abstract_params, dp_axes=dp_axes, zero1=zero1
+    )
+    in_shardings = (state["params"], state["opt"], _named(mesh, batch_specs))
+    out_shardings = (state["params"], state["opt"], None)
     return jax.jit(
         train_step,
         in_shardings=in_shardings,

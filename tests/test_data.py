@@ -111,3 +111,26 @@ def test_co2_buoyancy():
     z_first = (sat[..., 1] * np.arange(10)[None, None, :]).sum() / max(sat[..., 1].sum(), 1e-9)
     z_last = (sat[..., -1] * np.arange(10)[None, None, :]).sum() / max(sat[..., -1].sum(), 1e-9)
     assert z_last < z_first + 1e-6  # center of mass rises (z index falls)
+
+
+def test_store_codec_is_thread_safe():
+    """Chunk (de)compression runs on the read pool, the loader's prefetch
+    thread and thread-backend datagen at once; every round trip must stay
+    exact (a shared zstd context corrupts concurrent calls)."""
+    from concurrent.futures import ThreadPoolExecutor
+
+    from repro.data.store import _compress, _decompress
+
+    payloads = [
+        np.random.default_rng(i).standard_normal(16384).astype(np.float32).tobytes()
+        for i in range(16)
+    ]
+
+    def round_trips(i):
+        return all(
+            _decompress(_compress(payloads[(i + k) % 16])) == payloads[(i + k) % 16]
+            for k in range(40)
+        )
+
+    with ThreadPoolExecutor(max_workers=16) as pool:
+        assert all(pool.map(round_trips, range(64)))
