@@ -11,6 +11,11 @@ so distributed-vs-serial equivalence is testable to numerical precision.
 Architecture (paper Alg. 1): 1x1-conv encoder -> n_blocks x [spectral conv
 + 1x1 bypass, GELU] -> 2-layer decoder. Spectral weights are complex64 and
 dominate memory (as in the paper, where the FNO fills 80% of an 80GB A100).
+
+Each layer runs under a ``jax.named_scope``, which a profile shows in the
+op path of every device op: ``encoder``; ``blocks`` around the scan over
+the blocks, and in each block ``fft_fwd`` (with its all-to-alls), ``mix``,
+``fft_inv`` and ``bypass`` (1x1 conv, residual add, GELU); ``decoder``.
 """
 from __future__ import annotations
 
@@ -219,6 +224,7 @@ def _conv1x1(x: jax.Array, w: jax.Array, b: Optional[jax.Array]) -> jax.Array:
     return y
 
 
+@jax.named_scope("encoder")
 def _encoder(params: dict, x: jax.Array, cfg: FNOConfig) -> jax.Array:
     x = x.astype(cfg.dtype)
     return jax.nn.gelu(_conv1x1(x, params["encoder"]["w"], params["encoder"]["b"]))
@@ -252,6 +258,7 @@ def _encoder_from_prelift(params: dict, pre: jax.Array, cfg: FNOConfig) -> jax.A
     return jax.nn.gelu(pre + b[None, :, None, None, None, None])
 
 
+@jax.named_scope("decoder")
 def _decoder(params: dict, x: jax.Array, cfg: FNOConfig) -> jax.Array:
     d = params["decoder"]
     h = jax.nn.gelu(_conv1x1(x, d["w1"], d["b1"]))
@@ -261,6 +268,67 @@ def _decoder(params: dict, x: jax.Array, cfg: FNOConfig) -> jax.Array:
 
 def _bypass(x, w_b, b_b):
     return _conv1x1(x, w_b, b_b)
+
+
+@jax.named_scope("encoder")
+def _split_lift(params: dict, pre_static: jax.Array, x_dyn: jax.Array,
+                cfg: FNOConfig, n_static: int) -> jax.Array:
+    """First hidden state from a cached static-channel prelift and the
+    normalized dynamic channels, lifted here."""
+    pre = pre_static.astype(cfg.dtype) + encoder_prelift(
+        params, x_dyn, cfg, slice(n_static, None)
+    )
+    return _encoder_from_prelift(params, pre, cfg)
+
+
+def _spectral_block(x, w_spec, w_b, b_b, cfg: FNOConfig, forward, adjoint,
+                    kernel_dims, t_out=None, *, add_kept=None, bypass_x=None):
+    """One FNO block from its transform pair, each stage under its own
+    named scope (``fft_fwd``, ``mix``, ``fft_inv``, ``bypass``), so that
+    a profile gives every op of a block to a stage.
+
+    ``forward(x, fused)`` transforms and truncates; with ``fused`` it
+    leaves the dims the fused kernel truncates itself (``kernel_dims``,
+    and t when ``t_out`` is given) at full size, and ``adjoint(yf,
+    fused)`` then skips padding them. ``add_kept`` / ``bypass_x`` as in
+    ``fno_block``.
+    """
+    with jax.named_scope("fft_fwd"):
+        xf = forward(x, cfg.use_pallas)
+    with jax.named_scope("mix"):
+        if not cfg.use_pallas:
+            yf = spectral_apply(xf, w_spec, use_pallas=False)
+            if add_kept is not None:
+                yf = yf + add_kept.astype(yf.dtype)
+        elif add_kept is None:
+            yf = spectral_apply_fused(xf, w_spec, kernel_dims, t_out=t_out)
+        else:
+            yf = spectral_apply_fused_add(
+                xf, w_spec, add_kept, kernel_dims, t_out=t_out
+            )
+    with jax.named_scope("fft_inv"):
+        y = adjoint(yf, cfg.use_pallas)
+    with jax.named_scope("bypass"):
+        xb = x if bypass_x is None else bypass_x
+        return jax.nn.gelu(y + _bypass(xb, w_b, b_b))
+
+
+def _x_fused_block(forward, adjoint, x, w_spec, w_b, b_b, cfg: FNOConfig,
+                   axis, add_kept, bypass_x):
+    """A distributed block whose transforms truncate y, z and t before the
+    repartition; the fused kernel truncates (and pads) only x."""
+    c = cfg.comm_chunks
+    return _spectral_block(
+        x, w_spec, w_b, b_b, cfg,
+        lambda x, fused: forward(
+            x, cfg.modes, axis, trunc_x=not fused, comm_chunks=c
+        ),
+        lambda yf, fused: adjoint(
+            yf, cfg.grid, axis, out_dtype=cfg.dtype, pad_x=not fused,
+            comm_chunks=c,
+        ),
+        (cfg.grid[0], None, None), add_kept=add_kept, bypass_x=bypass_x,
+    )
 
 
 # ---------------------------------------------------------------------------
@@ -279,24 +347,15 @@ def fno_block(x, w_spec, w_b, b_b, cfg: FNOConfig, *, add_kept=None, bypass_x=No
     the inverse transform, and ``bypass_x``, the full activation the 1x1
     bypass runs on when ``x`` is only the dynamic remainder.
     """
-    if cfg.use_pallas:
-        nx, ny, nz, nt = cfg.grid
-        xf = dfft.serial_forward(x, cfg.modes, truncate=False)
-        if add_kept is None:
-            yf = spectral_apply_fused(xf, w_spec, (nx, ny, nz), t_out=nt // 2 + 1)
-        else:
-            yf = spectral_apply_fused_add(
-                xf, w_spec, add_kept, (nx, ny, nz), t_out=nt // 2 + 1
-            )
-        y = dfft.serial_adjoint(yf, cfg.grid, out_dtype=cfg.dtype, pre_padded=True)
-    else:
-        xf = dfft.serial_forward(x, cfg.modes)
-        yf = spectral_apply(xf, w_spec, use_pallas=False)
-        if add_kept is not None:
-            yf = yf + add_kept.astype(yf.dtype)
-        y = dfft.serial_adjoint(yf, cfg.grid, out_dtype=cfg.dtype)
-    xb = x if bypass_x is None else bypass_x
-    return jax.nn.gelu(y + _bypass(xb, w_b, b_b))
+    nx, ny, nz, nt = cfg.grid
+    return _spectral_block(
+        x, w_spec, w_b, b_b, cfg,
+        lambda x, fused: dfft.serial_forward(x, cfg.modes, truncate=not fused),
+        lambda yf, fused: dfft.serial_adjoint(
+            yf, cfg.grid, out_dtype=cfg.dtype, pre_padded=fused
+        ),
+        (nx, ny, nz), nt // 2 + 1, add_kept=add_kept, bypass_x=bypass_x,
+    )
 
 
 def _run_blocks(params: dict, h: jax.Array, cfg: FNOConfig, block_apply):
@@ -308,7 +367,8 @@ def _run_blocks(params: dict, h: jax.Array, cfg: FNOConfig, block_apply):
 
     if cfg.remat:
         body = jax.checkpoint(body)
-    h, _ = jax.lax.scan(body, h, params["blocks"])
+    with jax.named_scope("blocks"):
+        h, _ = jax.lax.scan(body, h, params["blocks"])
     return _decoder(params, h, cfg)
 
 
@@ -334,10 +394,7 @@ def fno_forward_split(
     cache paths both go through THIS function, so they are bit-identical
     to each other).
     """
-    pre = pre_static.astype(cfg.dtype) + encoder_prelift(
-        params, x_dyn, cfg, slice(n_static, None)
-    )
-    h = _encoder_from_prelift(params, pre, cfg)
+    h = _split_lift(params, pre_static, x_dyn, cfg, n_static)
     return _run_blocks(
         params, h, cfg,
         lambda h, blk: fno_block(h, _block_weights(blk), blk["w_bypass"], blk["b_bypass"], cfg),
@@ -380,14 +437,15 @@ def _fno_forward_deep_impl(params, pre_static, x_dyn, cfg, n_static, block_first
     block 0 on the dynamic REMAINDER ``h - h_static`` (its static kept-mode
     term arrives precomputed via ``block_first``'s closure), then the
     remaining blocks unchanged."""
-    pre_s = pre_static.astype(cfg.dtype)
-    pre = pre_s + encoder_prelift(params, x_dyn, cfg, slice(n_static, None))
-    h_full = _encoder_from_prelift(params, pre, cfg)
-    h_static = _encoder_from_prelift(params, pre_s, cfg)
+    h_full = _split_lift(params, pre_static, x_dyn, cfg, n_static)
+    with jax.named_scope("encoder"):
+        h_static = _encoder_from_prelift(params, pre_static.astype(cfg.dtype), cfg)
+        h_rem = h_full - h_static
     blocks = params["blocks"]
-    blk0 = jax.tree.map(lambda a: a[0], blocks)
-    h = block_first(h_full - h_static, blk0, h_full)
-    rest = {**params, "blocks": jax.tree.map(lambda a: a[1:], blocks)}
+    with jax.named_scope("blocks"):
+        blk0 = jax.tree.map(lambda a: a[0], blocks)
+        h = block_first(h_rem, blk0, h_full)
+        rest = {**params, "blocks": jax.tree.map(lambda a: a[1:], blocks)}
     return _run_blocks(rest, h, cfg, block_rest)
 
 
@@ -446,166 +504,51 @@ def fno_block_dist(x, w_spec, w_b, b_b, cfg: FNOConfig, axis_name: str,
 
     ``add_kept`` is the LOCAL shard of a cached kept-mode contribution
     ([b, co, 2mx, 2my/P, 2mz, mt] — same k_y sharding as ``w_spec``, see
-    ``contrib_spec``); ``bypass_x`` as in ``fno_block``.
+    ``contrib_spec``); ``bypass_x`` as in ``fno_block``. The all-to-alls
+    fall under the ``fft_fwd`` and ``fft_inv`` scopes.
     """
-    if cfg.use_pallas:
-        xf = dfft.dist_forward(
-            x, cfg.modes, axis_name, trunc_x=False, comm_chunks=cfg.comm_chunks
-        )
-        if add_kept is None:
-            yf = spectral_apply_fused(xf, w_spec, (cfg.grid[0], None, None))
-        else:
-            yf = spectral_apply_fused_add(
-                xf, w_spec, add_kept, (cfg.grid[0], None, None)
-            )
-        y = dfft.dist_adjoint(
-            yf, cfg.grid, axis_name, out_dtype=cfg.dtype,
-            pad_x=False, comm_chunks=cfg.comm_chunks,
-        )
-    else:
-        xf = dfft.dist_forward(x, cfg.modes, axis_name, comm_chunks=cfg.comm_chunks)
-        yf = spectral_apply(xf, w_spec, use_pallas=False)
-        if add_kept is not None:
-            yf = yf + add_kept.astype(yf.dtype)
-        y = dfft.dist_adjoint(
-            yf, cfg.grid, axis_name, out_dtype=cfg.dtype,
-            comm_chunks=cfg.comm_chunks,
-        )
-    xb = x if bypass_x is None else bypass_x
-    return jax.nn.gelu(y + _bypass(xb, w_b, b_b))
+    return _x_fused_block(dfft.dist_forward, dfft.dist_adjoint, x, w_spec,
+                          w_b, b_b, cfg, axis_name, add_kept, bypass_x)
 
 
 def fno_block_dist_31(x, w_spec, w_b, b_b, cfg: FNOConfig, axis_name: str,
                       *, add_kept=None, bypass_x=None):
     """Grady et al. [31] schedule: repartition the UNtruncated spectrum."""
     nx, ny, nz, nt = cfg.grid
-    if cfg.use_pallas:
-        xf = dfft.dist_forward_untruncated(
-            x, cfg.modes, axis_name, trunc_xzt=False,
-            comm_chunks=cfg.comm_chunks,
-        )
-        if add_kept is None:
-            yf = spectral_apply_fused(
-                xf, w_spec, (nx, None, nz), t_out=nt // 2 + 1
-            )
-        else:
-            yf = spectral_apply_fused_add(
-                xf, w_spec, add_kept, (nx, None, nz), t_out=nt // 2 + 1
-            )
-        y = dfft.dist_adjoint_untruncated(
-            yf, cfg.grid, axis_name, out_dtype=cfg.dtype,
-            pad_xzt=False, comm_chunks=cfg.comm_chunks,
-        )
-    else:
-        xf = dfft.dist_forward_untruncated(
-            x, cfg.modes, axis_name, comm_chunks=cfg.comm_chunks
-        )
-        yf = spectral_apply(xf, w_spec, use_pallas=False)
-        if add_kept is not None:
-            yf = yf + add_kept.astype(yf.dtype)
-        y = dfft.dist_adjoint_untruncated(
-            yf, cfg.grid, axis_name, out_dtype=cfg.dtype,
-            comm_chunks=cfg.comm_chunks,
-        )
-    xb = x if bypass_x is None else bypass_x
-    return jax.nn.gelu(y + _bypass(xb, w_b, b_b))
+    c = cfg.comm_chunks
+    return _spectral_block(
+        x, w_spec, w_b, b_b, cfg,
+        lambda x, fused: dfft.dist_forward_untruncated(
+            x, cfg.modes, axis_name, trunc_xzt=not fused, comm_chunks=c
+        ),
+        lambda yf, fused: dfft.dist_adjoint_untruncated(
+            yf, cfg.grid, axis_name, out_dtype=cfg.dtype, pad_xzt=not fused,
+            comm_chunks=c,
+        ),
+        (nx, None, nz), nt // 2 + 1, add_kept=add_kept, bypass_x=bypass_x,
+    )
 
 
 def fno_block_dist_eager(x, w_spec, w_b, b_b, cfg: FNOConfig, axis_name: str,
                          *, add_kept=None, bypass_x=None):
     """Beyond-paper: per-dim eager truncation (bit-equivalent, cheaper FFTs)."""
-    if cfg.use_pallas:
-        xf = dfft.dist_forward_eager(
-            x, cfg.modes, axis_name, trunc_x=False, comm_chunks=cfg.comm_chunks
-        )
-        if add_kept is None:
-            yf = spectral_apply_fused(xf, w_spec, (cfg.grid[0], None, None))
-        else:
-            yf = spectral_apply_fused_add(
-                xf, w_spec, add_kept, (cfg.grid[0], None, None)
-            )
-        y = dfft.dist_adjoint_eager(
-            yf, cfg.grid, axis_name, out_dtype=cfg.dtype,
-            pad_x=False, comm_chunks=cfg.comm_chunks,
-        )
-    else:
-        xf = dfft.dist_forward_eager(
-            x, cfg.modes, axis_name, comm_chunks=cfg.comm_chunks
-        )
-        yf = spectral_apply(xf, w_spec, use_pallas=False)
-        if add_kept is not None:
-            yf = yf + add_kept.astype(yf.dtype)
-        y = dfft.dist_adjoint_eager(
-            yf, cfg.grid, axis_name, out_dtype=cfg.dtype,
-            comm_chunks=cfg.comm_chunks,
-        )
-    xb = x if bypass_x is None else bypass_x
-    return jax.nn.gelu(y + _bypass(xb, w_b, b_b))
+    return _x_fused_block(dfft.dist_forward_eager, dfft.dist_adjoint_eager, x,
+                          w_spec, w_b, b_b, cfg, axis_name, add_kept, bypass_x)
 
 
 def fno_block_dist_2d(x, w_spec, w_b, b_b, cfg: FNOConfig, axis_names,
                       *, add_kept=None, bypass_x=None):
     """2-D pencil block: x sharded along both x and y, spectral weights
     sharded along k_y x k_z (matching dist_forward_2d's output layout)."""
-    if cfg.use_pallas:
-        xf = dfft.dist_forward_2d(
-            x, cfg.modes, axis_names, trunc_x=False, comm_chunks=cfg.comm_chunks
-        )
-        if add_kept is None:
-            yf = spectral_apply_fused(xf, w_spec, (cfg.grid[0], None, None))
-        else:
-            yf = spectral_apply_fused_add(
-                xf, w_spec, add_kept, (cfg.grid[0], None, None)
-            )
-        y = dfft.dist_adjoint_2d(
-            yf, cfg.grid, axis_names, out_dtype=cfg.dtype,
-            pad_x=False, comm_chunks=cfg.comm_chunks,
-        )
-    else:
-        xf = dfft.dist_forward_2d(
-            x, cfg.modes, axis_names, comm_chunks=cfg.comm_chunks
-        )
-        yf = spectral_apply(xf, w_spec, use_pallas=False)
-        if add_kept is not None:
-            yf = yf + add_kept.astype(yf.dtype)
-        y = dfft.dist_adjoint_2d(
-            yf, cfg.grid, axis_names, out_dtype=cfg.dtype,
-            comm_chunks=cfg.comm_chunks,
-        )
-    xb = x if bypass_x is None else bypass_x
-    return jax.nn.gelu(y + _bypass(xb, w_b, b_b))
+    return _x_fused_block(dfft.dist_forward_2d, dfft.dist_adjoint_2d, x,
+                          w_spec, w_b, b_b, cfg, axis_names, add_kept, bypass_x)
 
 
 def fno_block_dist_2d_eager(x, w_spec, w_b, b_b, cfg: FNOConfig, axis_names,
                             *, add_kept=None, bypass_x=None):
     """2-D pencil block with per-dim eager truncation."""
-    if cfg.use_pallas:
-        xf = dfft.dist_forward_2d_eager(
-            x, cfg.modes, axis_names, trunc_x=False, comm_chunks=cfg.comm_chunks
-        )
-        if add_kept is None:
-            yf = spectral_apply_fused(xf, w_spec, (cfg.grid[0], None, None))
-        else:
-            yf = spectral_apply_fused_add(
-                xf, w_spec, add_kept, (cfg.grid[0], None, None)
-            )
-        y = dfft.dist_adjoint_2d_eager(
-            yf, cfg.grid, axis_names, out_dtype=cfg.dtype,
-            pad_x=False, comm_chunks=cfg.comm_chunks,
-        )
-    else:
-        xf = dfft.dist_forward_2d_eager(
-            x, cfg.modes, axis_names, comm_chunks=cfg.comm_chunks
-        )
-        yf = spectral_apply(xf, w_spec, use_pallas=False)
-        if add_kept is not None:
-            yf = yf + add_kept.astype(yf.dtype)
-        y = dfft.dist_adjoint_2d_eager(
-            yf, cfg.grid, axis_names, out_dtype=cfg.dtype,
-            comm_chunks=cfg.comm_chunks,
-        )
-    xb = x if bypass_x is None else bypass_x
-    return jax.nn.gelu(y + _bypass(xb, w_b, b_b))
+    return _x_fused_block(dfft.dist_forward_2d_eager, dfft.dist_adjoint_2d_eager,
+                          x, w_spec, w_b, b_b, cfg, axis_names, add_kept, bypass_x)
 
 
 def _fno_forward_dist_impl(params, x, cfg, axis_name, block_fn):
@@ -625,10 +568,7 @@ def _fno_forward_dist_split_impl(params, pre_static, x_dyn, cfg, n_static, axis_
     # Split-encoder distributed forward: the prelift add and the dynamic
     # channel contraction are pointwise over the sharded spatial dims, so
     # they need no communication — only the blocks do (as in the fused path).
-    pre = pre_static.astype(cfg.dtype) + encoder_prelift(
-        params, x_dyn, cfg, slice(n_static, None)
-    )
-    h = _encoder_from_prelift(params, pre, cfg)
+    h = _split_lift(params, pre_static, x_dyn, cfg, n_static)
     return _run_blocks(
         params, h, cfg,
         lambda h, blk: block_fn(

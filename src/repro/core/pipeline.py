@@ -66,7 +66,8 @@ def _pipeline_blocks(blocks, h_micro, cfg: FNOConfig, axis_name: str):
         return (recv, outs), None
 
     outs0 = jnp.zeros_like(h_micro)
-    (_, outs), _ = jax.lax.scan(tick, (zeros, outs0), jnp.arange(n_ticks))
+    with jax.named_scope("blocks"):
+        (_, outs), _ = jax.lax.scan(tick, (zeros, outs0), jnp.arange(n_ticks))
     # Only the last stage holds real outputs; broadcast to all stages.
     outs = jnp.where(stage == p - 1, outs, jnp.zeros_like(outs))
     return jax.lax.psum(outs, axis_name)

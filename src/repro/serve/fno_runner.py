@@ -47,6 +47,7 @@ import jax
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro.common import tracing
 from repro.core.fno import (
     FNOConfig, deep_split_forward_and_specs, forward_and_specs, init_params,
     params_with_planes, split_forward_and_specs,
@@ -633,40 +634,38 @@ class FNORunner:
         )
 
     def step(self, slots: Sequence[Optional[ScenarioRequest]], active: Sequence[int]) -> list:
+        """One tick: stage the active slots' inputs into the bucket's host
+        batch, run the forward, feed the outputs back. Each phase is
+        recorded as a span (``common.tracing``)."""
         bucket = self.bucket_for(len(active))
-        grid = tuple(self.cfg.grid)
-        if self.n_static:
+        with tracing.span("fno_runner.stage"):
             # staged per tick = per rollout step: the cache turns the
             # static normalize+prelift into a lookup; without it (cache
             # disabled) each tick recomputes — exactly the pre-cache cost
-            pre_b = np.zeros((bucket, self.cfg.width) + grid, np.float32)
-            xd_b = np.zeros(
-                (bucket, self.cfg.in_channels - self.n_static) + grid, np.float32
-            )
-            deep = self._forward_deep is not None
-            if deep:
-                ck_b = np.zeros(
-                    (bucket, self.cfg.width) + self.cfg.mode_shape, np.complex64
-                )
+            forward, batch = self._served_forward(bucket)
             for j, i in enumerate(active):
-                entry = self._static_entry(self._static_key[i], self._static_raw[i])
-                pre_b[j] = entry.prelift
-                xd_b[j] = self._dyn[i]
-                if deep:
-                    ck_b[j] = entry.contribution
-            if deep:
-                yb = np.asarray(
-                    self._forward_deep(self.params, ck_b, pre_b, xd_b)
-                )
-            else:
-                yb = np.asarray(self._forward_split(self.params, pre_b, xd_b))
-        else:
-            xb = np.zeros((bucket, self.cfg.in_channels) + grid, np.float32)
-            for j, i in enumerate(active):
-                xb[j] = self._inputs[i]
-            yb = np.asarray(self._forward(self.params, xb))
+                if self.n_static:
+                    entry = self._static_entry(self._static_key[i], self._static_raw[i])
+                    rows = (entry.prelift, self._dyn[i])
+                    if self._forward_deep is not None:
+                        rows = (entry.contribution,) + rows
+                else:
+                    rows = (self._inputs[i],)
+                for arr, row in zip(batch, rows):
+                    arr[j] = row
+        with tracing.span(
+            "fno_runner.forward", bytes=sum(arr.nbytes for arr in batch)
+        ):
+            yb = np.asarray(forward(self.params, *batch))
         self.batched_steps += 1
+        with tracing.span("fno_runner.feedback"):
+            return self._feed_back(slots, active, yb)
+
+    def _feed_back(self, slots, active, yb: np.ndarray) -> list:
+        """Append each active slot's de-normalized output to its request
+        and re-encode the next rollout input; returns the slots done."""
         finished = []
+        grid = tuple(self.cfg.grid)
         n_dyn = self.cfg.in_channels - self.n_static
         for j, i in enumerate(active):
             req = slots[i]

@@ -36,7 +36,9 @@ Requests are opaque to the scheduler except for the attributes it manages:
 ``done`` (set True on retirement/failure), ``error`` (the admit exception,
 on failure), and the latency timestamps (``submitted_s`` / ``admitted_s``
 / ``finished_s``, ``time.perf_counter`` values) that the serving CLIs
-report per-request latency from. Two OPTIONAL request attributes feed the
+report per-request latency from; each admitted request's wait in the
+queue is recorded from them as a ``scheduler.queued`` span
+(``common.tracing``), and each tick as a ``scheduler.step`` span. Two OPTIONAL request attributes feed the
 admission policy: ``priority`` (int, higher admitted first when slots
 contend) and ``deadline_s`` (relative seconds from submission; within a
 priority class the earliest absolute deadline is admitted first — EDF).
@@ -49,6 +51,8 @@ import time
 import warnings
 from collections import deque
 from typing import List, Optional, Protocol, Sequence
+
+from repro.common import tracing
 
 
 class ModelRunner(Protocol):
@@ -142,6 +146,10 @@ class Scheduler:
                     self._fail(request, exc)
                     continue
                 request.admitted_s = time.perf_counter()
+                tracing.record(
+                    "scheduler.queued", request.submitted_s, request.admitted_s,
+                    rid=getattr(request, "rid", None),
+                )
                 # followers that attached while this primary was queued
                 # become admitted with it (they ride this very slot)
                 for follower in self._followers.get(id(request), []):
@@ -155,21 +163,22 @@ class Scheduler:
     def step(self) -> int:
         """One tick: admit, one batched runner step, retire. Returns the
         number of slots that were active during the step."""
-        self.admit_waiting()
-        active = self.active_slots()
-        if not active:
-            return 0
-        finished = self.runner.step(self.slots, active)
-        self.steps += 1
-        for i in finished:
-            request = self.slots[i]
-            self.runner.retire(i, request)
-            request.done = True
-            request.finished_s = time.perf_counter()
-            self.finished.append(request)
-            self.slots[i] = None
-            self._resolve_dedup(request)
-        return len(active)
+        with tracing.span("scheduler.step"):
+            self.admit_waiting()
+            active = self.active_slots()
+            if not active:
+                return 0
+            finished = self.runner.step(self.slots, active)
+            self.steps += 1
+            for i in finished:
+                request = self.slots[i]
+                self.runner.retire(i, request)
+                request.done = True
+                request.finished_s = time.perf_counter()
+                self.finished.append(request)
+                self.slots[i] = None
+                self._resolve_dedup(request)
+            return len(active)
 
     def run_until_done(self, max_steps: int = 1000) -> list:
         """Drive ticks until the pool drains. ``max_steps`` budgets THIS

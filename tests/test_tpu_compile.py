@@ -12,6 +12,7 @@ module is imported: only one process may load the TPU library.
 """
 import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -152,7 +153,13 @@ def test_one_chip_serving_step_compiles(topo, compiled_kernels):
             (bucket, cfg.in_channels - n_static) + cfg.grid, jnp.float32
         ),
     ).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    # the kernel keeps its name, under the blocks' mix scope
+    kernels = [(n, op) for n, op in re.findall(
+        r"(\S+) = .*custom-call\(.*op_name=\"([^\"]+)\"", text) if "pallas_call" in op]
+    assert kernels and all(n.startswith("%spectral_fused.") and "/blocks/" in op
+                           and "/mix/" in op for n, op in kernels), kernels
     _peak_fits(compiled, topo.devices[0])
 
 
