@@ -17,11 +17,15 @@ The serving loop records, per scheduler tick:
 
 * ``scheduler.step``: the whole tick (admission, the runner's step,
   retirement);
-* ``fno_runner.stage``: building the bucket's host input arrays (geomodel
-  cache lookups, copies into the batch);
-* ``fno_runner.forward``: the jitted forward from its call on host arrays
-  until its output is a host array (upload, device compute, download);
-  attribute ``bytes``, the host input bytes uploaded;
+* ``fno_runner.stage``: building the bucket's inputs (geomodel cache
+  lookups, copies into the host batch, and where the runner keeps the
+  static rows on the device, their assembly there); attributes
+  ``resident_hits`` and ``resident_fills`` on that path: the static rows
+  served from the runner's device table, and those uploaded into it;
+* ``fno_runner.forward``: the jitted forward from its call until its
+  output is a host array (upload, device compute, download); attribute
+  ``bytes``, the host bytes this tick uploads (the host inputs and any
+  table fill);
 * ``fno_runner.feedback``: de-normalizing the outputs, and for rollouts
   the feedback into the next step's inputs;
 
@@ -66,7 +70,9 @@ def _open() -> list:
 @contextlib.contextmanager
 def span(name: str, **attrs):
     """Record the enclosed host work as span ``name`` with ``attrs``, also
-    when it raises."""
+    when it raises. Yields the span's attribute dict: what the body adds to
+    it is recorded with the span (the profiler's event keeps only the
+    attributes given at entry)."""
     stack = _open()
     parent = stack[-1] if stack else None
     sid = next(_ids)
@@ -74,7 +80,7 @@ def span(name: str, **attrs):
     start = time.perf_counter()
     try:
         with jax.profiler.TraceAnnotation(name, **attrs):
-            yield
+            yield attrs
     finally:
         end = time.perf_counter()
         stack.pop()

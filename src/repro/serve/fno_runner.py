@@ -34,16 +34,25 @@ that surrogate through the same slot scheduler that serves LLM tokens:
     ``feedback`` then produces only the DYNAMIC channels — the geomodel
     persists across rollout steps without re-normalize/re-lift. The runner
     also keys requests by content (``request_key``) so the scheduler can
-    dedup identical in-flight scenarios.
+    dedup identical in-flight scenarios;
+  * with a cache, the runner also holds the static rows the forward reads
+    (the prelift, and the contribution on the deep level) on the device,
+    in a table of its own keyed like the cache: a tick uploads only the
+    dynamic channels, and the bucket's static inputs are stacked on the
+    device from the table's rows. The rows are the cache entry's arrays,
+    uploaded unchanged, and the tick runs the program compiled for a host
+    batch, so the outputs are those of host staging, bit for bit.
 """
 from __future__ import annotations
 
 import dataclasses
 import json
 import os
+from collections import OrderedDict
 from typing import Callable, List, Optional, Sequence, Tuple
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -123,6 +132,15 @@ def _slice_normalizer(norm: Normalizer, sl: slice) -> Normalizer:
     if norm.identity or norm.mean.ndim == 0:
         return norm
     return Normalizer(norm.mean[:, sl], norm.scale[:, sl])
+
+
+def _stack_rows(bucket: int, *inputs) -> tuple:
+    """Each input's rows stacked along a new batch axis and zero-padded to
+    ``bucket`` rows, as a zeroed host batch pads them."""
+    return tuple(
+        jnp.pad(jnp.stack(rows), [(0, bucket - len(rows))] + [(0, 0)] * rows[0].ndim)
+        for rows in inputs
+    )
 
 
 def _bucket_ladder(max_slots: int, n_dp: int) -> tuple:
@@ -251,7 +269,9 @@ class FNORunner:
         )
         self._forward_split = None
         self._forward_deep = None
+        static_specs = ()
         if n_static:
+            static_specs = (x_spec,)
             split_fwd, _, _ = split_forward_and_specs(
                 mesh, cfg, n_static, dp_axes=("data",), model_axis=model_axis,
                 planes=self._planes,
@@ -268,6 +288,7 @@ class FNORunner:
                     mesh, cfg, n_static, dp_axes=("data",),
                     model_axis=model_axis, planes=self._planes,
                 )
+                static_specs = (c_spec, x_spec)
                 self._forward_deep = jax.jit(
                     deep_fwd,
                     in_shardings=(
@@ -276,6 +297,22 @@ class FNORunner:
                     ),
                     out_shardings=self._x_sharding,
                 )
+        # device table of static rows (see the module docstring), LRU over
+        # at most max_slots geomodel keys: the most one bucket reads. Each
+        # row is placed as the activations are along the model axes and
+        # replicated over "data"; the jitted stack lays the bucket out
+        # over "data" as the forward's in_shardings ask.
+        self._resident: "OrderedDict[str, tuple]" = OrderedDict()
+        self._compiled: dict = {}  # bucket -> compiled_step
+        self.resident_hits = 0
+        self.resident_fills = 0
+        self._row_shardings = tuple(
+            NamedSharding(mesh, P(*spec[1:])) for spec in static_specs
+        )
+        self._stack = jax.jit(
+            _stack_rows, static_argnums=0,
+            out_shardings=tuple(NamedSharding(mesh, s) for s in static_specs),
+        )
         self.x_normalizer = x_normalizer or Normalizer.from_stats(None)
         self.y_normalizer = y_normalizer or Normalizer.from_stats(None)
         self._x_norm_static = _slice_normalizer(self.x_normalizer, slice(0, n_static))
@@ -587,42 +624,71 @@ class FNORunner:
             self._inputs[slot] = self._encode(req.x)
         self._remaining[slot] = int(req.steps)
 
-    def _served_forward(self, bucket: int):
-        """(jitted forward, zero batch args after params) for one bucket:
-        the deep split, the split, or the plain forward — whichever
-        ``step`` runs for this runner."""
+    def _served_forward(self, bucket: int, static: bool = True):
+        """(jitted forward, zero host batch args after params) for one
+        bucket: the deep split, the split, or the plain forward — whichever
+        ``step`` runs for this runner. ``static=False`` leaves out the
+        static args, which the device table then supplies, and gives the
+        program compiled for the host batch (``compiled_step``): traced on
+        device arrays, whose types carry their sharding, the jitted forward
+        would compile a different program, with different rounding."""
         grid = tuple(self.cfg.grid)
         if not self.n_static:
             return self._forward, (
                 np.zeros((bucket, self.cfg.in_channels) + grid, np.float32),
             )
-        pre = np.zeros((bucket, self.cfg.width) + grid, np.float32)
         xd = np.zeros(
             (bucket, self.cfg.in_channels - self.n_static) + grid, np.float32
         )
+        if not static:
+            return self.compiled_step(bucket), (xd,)
+        pre = np.zeros((bucket, self.cfg.width) + grid, np.float32)
         if self._forward_deep is None:
             return self._forward_split, (pre, xd)
         ck = np.zeros((bucket, self.cfg.width) + self.cfg.mode_shape, np.complex64)
         return self._forward_deep, (ck, pre, xd)
 
+    @property
+    def _holds_rows(self) -> bool:
+        """Whether ticks read the static rows from the device table: with
+        static channels and a cache (without one, the uncached reference
+        path recomputes and uploads them every tick)."""
+        return bool(self.n_static) and self.cache is not None
+
     def warmup(self) -> float:
-        """jit-compile every bucket shape up front (zero batches); returns
-        seconds spent, so drivers can report compile time separately from
+        """jit-compile every bucket shape up front (zero batches), and with
+        the device table its stack for every active count; returns seconds
+        spent, so drivers can report compile time separately from
         steady-state serving throughput."""
         import time as _time
 
         t0 = _time.perf_counter()
         for b in self.buckets:
             fwd, args = self._served_forward(b)
+            if self._holds_rows:
+                # the table's path: the host batch's program on static args
+                # stacked on the device, from every row count this bucket
+                # serves; one stacked bucket and one zero row held at a time
+                zero = [jnp.zeros(a.shape[1:], a.dtype, device=sh)
+                        for a, sh in zip(args, self._row_shardings)]
+                for n in range(1, b):
+                    if self.bucket_for(n) == b:
+                        jax.block_until_ready(self._stack(b, *((z,) * n for z in zero)))
+                fwd = self.compiled_step(b)
+                args = self._stack(b, *((z,) * b for z in zero)) + args[-1:]
+                del zero
             jax.block_until_ready(fwd(self.params, *args))
         return _time.perf_counter() - t0
 
     def compiled_step(self, bucket: int):
-        """The compiled device program ``step`` runs at ``bucket``: its
-        ``as_text()`` and ``memory_analysis()`` describe what the device
-        executes (e.g. whether a Pallas kernel is in it)."""
-        fwd, args = self._served_forward(bucket)
-        return fwd.lower(self.params, *args).compile()
+        """The compiled device program ``step`` runs at ``bucket``, lowered
+        from the host batch and kept: its ``as_text()`` and
+        ``memory_analysis()`` describe what the device executes (e.g.
+        whether a Pallas kernel is in it)."""
+        if bucket not in self._compiled:
+            fwd, args = self._served_forward(bucket)
+            self._compiled[bucket] = fwd.lower(self.params, *args).compile()
+        return self._compiled[bucket]
 
     def bucket_for(self, n_active: int) -> int:
         for b in self.buckets:
@@ -633,29 +699,72 @@ class FNORunner:
             f"{self.buckets[-1]}"
         )
 
+    def _static_rows(self, entry: GeomodelEntry) -> tuple:
+        """The entry's rows the forward reads as static inputs, in its
+        argument order."""
+        if self._forward_deep is None:
+            return (entry.prelift,)
+        return (entry.contribution, entry.prelift)
+
+    def _resident_statics(self, bucket: int, active: Sequence[int], stage: dict):
+        """The bucket's static inputs stacked on the device from the table,
+        uploading the rows of keys it lacks; returns them and the bytes
+        uploaded. The tick's resident keys are touched first, so a fill's
+        LRU eviction never drops a row this tick reads."""
+        keys = [self._static_key[i] for i in active]
+        for key in keys:
+            if key in self._resident:
+                self._resident.move_to_end(key)
+        rows, hits, fills, filled = [], 0, 0, 0
+        for i, key in zip(active, keys):
+            # looked up every tick as without the table: the cache's
+            # hit-rate and LRU order keep counting reuse per rollout step
+            entry = self._static_entry(key, self._static_raw[i])
+            if key in self._resident:
+                hits += 1
+            else:
+                host = self._static_rows(entry)
+                self._resident[key] = tuple(
+                    jax.device_put(a, sh) for a, sh in zip(host, self._row_shardings)
+                )
+                fills += 1
+                filled += sum(a.nbytes for a in host)
+                if len(self._resident) > self.max_slots:
+                    self._resident.popitem(last=False)
+            rows.append(self._resident[key])
+        self.resident_hits += hits
+        self.resident_fills += fills
+        stage.update(resident_hits=hits, resident_fills=fills)
+        return self._stack(bucket, *zip(*rows)), filled
+
     def step(self, slots: Sequence[Optional[ScenarioRequest]], active: Sequence[int]) -> list:
         """One tick: stage the active slots' inputs into the bucket's host
-        batch, run the forward, feed the outputs back. Each phase is
-        recorded as a span (``common.tracing``)."""
+        batch (the static rows, where the device table holds them, stacked
+        on the device instead), run the forward, feed the outputs back.
+        Each phase is recorded as a span (``common.tracing``)."""
         bucket = self.bucket_for(len(active))
-        with tracing.span("fno_runner.stage"):
+        resident = self._holds_rows
+        with tracing.span("fno_runner.stage") as stage:
             # staged per tick = per rollout step: the cache turns the
             # static normalize+prelift into a lookup; without it (cache
             # disabled) each tick recomputes — exactly the pre-cache cost
-            forward, batch = self._served_forward(bucket)
+            forward, batch = self._served_forward(bucket, static=not resident)
             for j, i in enumerate(active):
-                if self.n_static:
+                if resident:
+                    rows = (self._dyn[i],)
+                elif self.n_static:
                     entry = self._static_entry(self._static_key[i], self._static_raw[i])
-                    rows = (entry.prelift, self._dyn[i])
-                    if self._forward_deep is not None:
-                        rows = (entry.contribution,) + rows
+                    rows = self._static_rows(entry) + (self._dyn[i],)
                 else:
                     rows = (self._inputs[i],)
                 for arr, row in zip(batch, rows):
                     arr[j] = row
-        with tracing.span(
-            "fno_runner.forward", bytes=sum(arr.nbytes for arr in batch)
-        ):
+            uploaded = sum(arr.nbytes for arr in batch)
+            if resident:
+                statics, filled = self._resident_statics(bucket, active, stage)
+                batch = statics + batch
+                uploaded += filled
+        with tracing.span("fno_runner.forward", bytes=uploaded):
             yb = np.asarray(forward(self.params, *batch))
         self.batched_steps += 1
         with tracing.span("fno_runner.feedback"):

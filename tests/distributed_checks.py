@@ -454,6 +454,43 @@ def fno_planes_serving_forward_matches_serial():
         np.asarray(back["blocks"]["w_spec"]), np.asarray(params["blocks"]["w_spec"]))
 
 
+@check
+def fno_runner_device_table_matches_uncached():
+    """The serving runner on a (data 2 x model 2) mesh: served through its
+    device table of static rows (held sharded along the model axis, the
+    bucket stacked over data), outputs equal the same runner's uncached
+    serving bit for bit, at both cache levels."""
+    from repro.serve import FNORunner, GeomodelCache, ScenarioRequest, Scheduler
+
+    cfg = FNOConfig(grid=(16, 16, 8, 8), modes=(4, 4, 2, 3), width=6,
+                    in_channels=2, out_channels=1, n_blocks=2, decoder_dim=8,
+                    use_pallas=True)
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    rng = np.random.default_rng(5)
+    geos = [rng.normal(size=(1,) + cfg.grid).astype(np.float32) for _ in range(2)]
+    xs = [np.concatenate([geos[g], rng.normal(size=(1,) + cfg.grid).astype(np.float32)])
+          for g in (0, 1, 0)]
+
+    def serve(runner):
+        sched = Scheduler(runner, 2)
+        for i, x in enumerate(xs):
+            sched.submit(ScenarioRequest(rid=i, x=x, steps=2))
+        done = sorted(sched.run_until_done(max_steps=50), key=lambda r: r.rid)
+        assert len(done) == len(xs)
+        return [y for r in done for y in r.outputs]
+
+    mesh = make_mesh((2, 2), ("data", "model"))
+    for level in ("deep", "prelift"):
+        runner = FNORunner(cfg, params, mesh=mesh, model_axis="model", max_slots=2,
+                           n_static=1, cache=GeomodelCache(), cache_level=level)
+        runner.warmup()
+        warm = serve(runner)
+        assert runner.resident_fills == 2 and runner.resident_hits == 4, level
+        runner.cache = None
+        for yw, yc in zip(warm, serve(runner)):
+            np.testing.assert_array_equal(yw, yc)
+
+
 def main():
     failed = []
     for fn in CHECKS:

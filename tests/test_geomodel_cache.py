@@ -8,6 +8,12 @@ Covers this PR's contract:
   * the property holds at BOTH cache levels: ``prelift`` (encoder-only)
     and ``deep`` (the block-input split serving cached first-block
     kept-mode spectra/contribution through ``fno_forward_deep_split``);
+  * the runner's device table of static rows: served outputs equal
+    uncached serving bit for bit across bucket sizes, admission orders,
+    slot reuse and a geomodel pushed out of the table and served again;
+    the table holds at most ``max_slots`` geomodels and never drops a row
+    the tick reads; ticks run only programs ``warmup`` compiled; a cache
+    shared across runners still hits and holds host arrays only;
   * the split forward (cached static prelift + dynamic lift) matches the
     fused ``fno_forward`` to float tolerance, and so does the deep split
     (``spectral_prelift`` + ``fno_forward_deep_split``);
@@ -36,9 +42,10 @@ from repro.core import (
 from repro.core.partition import make_mesh
 from repro.data.loader import Normalizer
 from repro.serve import (
-    FNORunner, GeomodelCache, GeomodelEntry, ScenarioRequest, Scheduler,
-    content_key,
+    DictCacheStore, FNORunner, GeomodelCache, GeomodelEntry, ScenarioRequest,
+    Scheduler, content_key,
 )
+from repro.serve.geomodel_cache import LEVELS
 
 # Tiny FNO with 2 static (geomodel) + 1 dynamic channel; module-level so
 # the jit cache persists across hypothesis examples.
@@ -145,6 +152,102 @@ def test_warm_cache_bitwise_identical_to_cold(
         assert rc.rid == rw.rid and len(rc.outputs) == len(rw.outputs) == steps
         for yc, yw in zip(rc.outputs, rw.outputs):
             np.testing.assert_array_equal(yc, yw)
+
+
+def _assert_same_outputs(got, want):
+    got, want = sorted(got, key=lambda r: r.rid), sorted(want, key=lambda r: r.rid)
+    assert [r.rid for r in got] == [r.rid for r in want]
+    for rg, rw in zip(got, want):
+        assert len(rg.outputs) == len(rw.outputs) == rg.steps
+        for yg, yw in zip(rg.outputs, rw.outputs):
+            np.testing.assert_array_equal(yg, yw)
+
+
+@pytest.mark.parametrize("level", ["deep", "prelift"])
+def test_device_table_bitwise_identical_to_uncached_across_buckets(level):
+    """Served through the device table, outputs equal uncached serving bit
+    for bit: one active slot (bucket 1), then three geomodels sharing
+    buckets of 4 and 2 as slots are reused and drain; and the ticks run
+    only the stack and forward programs ``warmup`` compiled."""
+    runner = _make_runner(cache=GeomodelCache(), cache_level=level, buckets=None)
+    assert runner.buckets == (1, 2, 4)
+    runner.warmup()
+    compiled = (runner._stack._cache_size(), dict(runner._compiled))
+
+    def requests():
+        plan = [(0, 2), (1, 3), (0, 1), (2, 2), (1, 1), (2, 3)]
+        return [_scenario(i, g, s) for i, (g, s) in enumerate(plan)]
+
+    warm, _ = _serve(runner, requests(), BUCKET, interleave=1, split=1)
+    assert (runner._stack._cache_size(), runner._compiled) == compiled
+    assert runner.resident_fills == 3 and runner.resident_hits > 0
+    runner.cache = None
+    cold, _ = _serve(runner, requests(), BUCKET, interleave=1, split=1)
+    _assert_same_outputs(warm, cold)
+
+
+def test_device_table_is_bounded_and_keeps_the_ticks_rows():
+    """The table holds at most ``max_slots`` geomodels, LRU; a fill never
+    evicts a row the same tick reads (the tick's resident keys are touched
+    before any fill), so that row is not uploaded again."""
+    runner = _make_runner(cache=GeomodelCache(), max_slots=2, buckets=(2,))
+    key = [content_key(g) for g in GEOMODELS]
+    slots = [_scenario(0, 0, steps=9), _scenario(1, 2, steps=9)]
+
+    def tick(active):
+        before = (runner.resident_hits, runner.resident_fills)
+        runner.step(slots, active)
+        assert len(runner._resident) <= runner.max_slots
+        assert all(runner._static_key[i] in runner._resident for i in active)
+        return runner.resident_hits - before[0], runner.resident_fills - before[1]
+
+    def admit(slot, req):
+        slots[slot] = req
+        runner.admit(slot, req)
+
+    for i, req in enumerate(slots):
+        admit(i, req)
+    assert tick([0, 1]) == (0, 2)   # rows of geomodels 0 and 2
+    assert tick([0]) == (1, 0)      # 2 is now the LRU row
+    admit(1, _scenario(2, 1, steps=9))
+    assert tick([0, 1]) == (1, 1)   # 1 fills and evicts 2
+    assert list(runner._resident) == [key[0], key[1]]
+    held = runner._resident[key[0]]
+    admit(0, _scenario(3, 2, steps=9))
+    admit(1, _scenario(4, 0, steps=9))
+    assert tick([0, 1]) == (1, 1)   # 2 fills first and evicts 1, not the LRU 0 read after it
+    assert list(runner._resident) == [key[0], key[2]]
+    assert runner._resident[key[0]] is held
+
+
+@pytest.mark.parametrize("level", ["deep", "prelift"])
+def test_geomodel_pushed_out_of_the_table_serves_the_same_bits(level):
+    """A one-row table: a geomodel served, pushed out by another and served
+    again is uploaded again and gives the same bits."""
+    runner = _make_runner(cache=GeomodelCache(), cache_level=level,
+                          max_slots=1, buckets=(1,))
+    first, _ = _serve(runner, [_scenario(0, 0, 2)], 1)
+    _serve(runner, [_scenario(1, 1, 2)], 1)
+    again, _ = _serve(runner, [_scenario(0, 0, 2)], 1)
+    assert (runner.resident_fills, runner.resident_hits) == (3, 3)
+    _assert_same_outputs(again, first)
+
+
+def test_shared_cache_hits_across_runners_and_holds_host_arrays():
+    """Two runners sharing one ``GeomodelCache`` and a fleet store: the
+    second runner's table fills from the entry the first computed (a cache
+    hit), and the cache and the store hold numpy arrays, never a device
+    row."""
+    cache, store = GeomodelCache(), DictCacheStore()
+    runners = [_make_runner(cache=cache, cache_store=store) for _ in range(2)]
+    served = [_serve(r, [_scenario(0, 0, 2)], 1)[0] for r in runners]
+    _assert_same_outputs(*served)
+    assert (cache.stats["misses"], cache.stats["hits"]) == (1, 3)
+    assert [(r.resident_fills, r.resident_hits) for r in runners] == [(1, 1)] * 2
+    arrays = [getattr(e, n) for e in cache._entries.values() for n in LEVELS]
+    arrays += [a for fields in store._data.values() for a in fields.values()]
+    assert len([a for a in arrays if a is not None]) == 8
+    assert all(type(a) is np.ndarray for a in arrays if a is not None)
 
 
 def test_cache_hit_rate_counts_requests_and_rollout_steps():

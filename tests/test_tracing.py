@@ -137,14 +137,20 @@ BUCKET = 2
 def test_serving_tick_spans(n_static):
     runner = FNORunner(CFG, PARAMS, mesh=make_mesh((1,), ("data",)), model_axis=None,
                        max_slots=BUCKET, buckets=(BUCKET,), n_static=n_static)
-    attr = "_forward_deep" if n_static else "_forward"
-    forward, uploaded = getattr(runner, attr), []
+    uploaded = []
 
-    def spy(params, *batch):
-        uploaded.append(sum(a.nbytes for a in batch))
-        return forward(params, *batch)
+    def spied(forward):
+        def spy(params, *batch):
+            # host arrays only: the device table's rows are already there
+            uploaded.append(sum(a.nbytes for a in batch if isinstance(a, np.ndarray)))
+            return forward(params, *batch)
+        return spy
 
-    setattr(runner, attr, spy)
+    if n_static:   # the device table's path runs the host batch's compiled program
+        compiled = runner.compiled_step
+        runner.compiled_step = lambda bucket: spied(compiled(bucket))
+    else:
+        runner._forward = spied(runner._forward)
     rng = np.random.default_rng(0)
     reqs = [ScenarioRequest(rid=i, x=rng.normal(size=(2,) + CFG.grid).astype(np.float32),
                             steps=2) for i in range(3)]
@@ -161,10 +167,22 @@ def test_serving_tick_spans(n_static):
     for phase in ("fno_runner.stage", "fno_runner.forward", "fno_runner.feedback"):
         assert sorted(r.parent for r in named(phase)) == ids, phase
     n = int(np.prod(CFG.grid))
-    per_row = 4 * 2 * n if not n_static else (
-        4 * CFG.width * n + 4 * n + 8 * CFG.width * int(np.prod(CFG.mode_shape)))
+    stages = named("fno_runner.stage")
     got = [r.attrs["bytes"] for r in named("fno_runner.forward")]
-    assert got == uploaded == [BUCKET * per_row] * len(steps)
+    if not n_static:
+        assert got == uploaded == [BUCKET * 4 * 2 * n] * len(steps)
+        assert not any(s.attrs for s in stages)
+    else:
+        # every tick uploads the bucket's dynamic channel; a geomodel's deep
+        # rows (prelift, contribution) go up once, when the device table
+        # takes them: ticks serve (r0, r1), (r0, r1), (r2), (r2)
+        xd = BUCKET * 4 * n
+        entry = 4 * CFG.width * n + 8 * CFG.width * int(np.prod(CFG.mode_shape))
+        counts = [(s.attrs["resident_hits"], s.attrs["resident_fills"]) for s in stages]
+        assert counts == [(0, 2), (2, 0), (0, 1), (1, 0)]
+        assert (runner.resident_hits, runner.resident_fills) == (3, 3)
+        assert uploaded == [xd] * len(steps)
+        assert got == [xd + fills * entry for _, fills in counts]
     queued = {r.attrs["rid"]: r for r in named("scheduler.queued")}
     assert sorted(queued) == [0, 1, 2]
     for r in reqs:
