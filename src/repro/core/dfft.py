@@ -29,7 +29,6 @@ from repro.core.repartition import repartition
 
 # Dim indices in the canonical [b, c, x, y, z, t] layout.
 BDIM, CDIM, XDIM, YDIM, ZDIM, TDIM = range(6)
-SPATIAL_DIMS = (XDIM, YDIM, ZDIM, TDIM)
 
 
 # ---------------------------------------------------------------------------
@@ -70,50 +69,30 @@ def pad_rfft(x: jax.Array, axis: int, n_bins: int) -> jax.Array:
     return jnp.concatenate([x, jnp.zeros(pad_shape, x.dtype)], axis=axis)
 
 
-def truncate_modes(
-    xf: jax.Array, modes: Sequence[int], axes: Sequence[int] = SPATIAL_DIMS
-) -> jax.Array:
-    """Truncate all spatial dims; the last axis in ``axes`` is the rFFT dim."""
-    *full_axes, rfft_axis = axes
-    mx = modes[: len(full_axes)]
-    for axis, m in zip(full_axes, mx):
-        xf = truncate_full(xf, axis, m)
-    return truncate_rfft(xf, rfft_axis, modes[-1])
-
-
-def pad_modes(
-    xf: jax.Array,
-    full_sizes: Sequence[int],
-    axes: Sequence[int] = SPATIAL_DIMS,
-) -> jax.Array:
-    """Adjoint of truncate_modes. full_sizes includes the rFFT bin count."""
-    *full_axes, rfft_axis = axes
-    for axis, n in zip(full_axes, full_sizes[:-1]):
-        xf = pad_full(xf, axis, n)
-    return pad_rfft(xf, rfft_axis, full_sizes[-1])
-
-
 # ---------------------------------------------------------------------------
-# Serial oracle: S ∘ F over all four dims at once.
+# Serial oracle: S ∘ F over all four dims, each dim truncated right after
+# its own transform (truncation along one dim commutes with the FFT along
+# another, so later FFTs run on already-truncated tensors).
 # ---------------------------------------------------------------------------
 
 def serial_forward(
-    x: jax.Array, modes: Sequence[int], *, truncate: bool = True
+    x: jax.Array, modes: Sequence[int], *, trunc_x: bool = True
 ) -> jax.Array:
-    """rFFT over t + 3-D FFT over (x,y,z), then truncation.
+    """rFFT over t, then FFTs over z, y and x, each followed by its own S.
 
-    x: real [b,c,nx,ny,nz,nt]. Equivalent to rfftn over all four dims, but
-    XLA only lowers FFTs of rank <= 3, so the 4-D transform is composed
-    from a 1-D rFFT and a 3-D FFT (per-axis FFTs commute).
+    x: real [b,c,nx,ny,nz,nt] -> complex [b,c,2mx,2my,2mz,mt]. Equal to
+    rfftn over all four dims followed by corner truncation (XLA only
+    lowers FFTs of rank <= 3, so the transform is composed per dim).
 
-    ``truncate=False`` returns the full spectrum — used by the fused
-    Pallas path, whose kernel performs S (and S^T) itself.
+    ``trunc_x=False`` skips the final S_x, leaving x at full size nx — the
+    fused Pallas kernel truncates x itself, as on the pencil path.
     """
-    xf = jnp.fft.rfft(x.astype(jnp.float32), axis=TDIM)
-    xf = jnp.fft.fftn(xf, axes=(XDIM, YDIM, ZDIM))
-    if truncate:
-        xf = truncate_modes(xf, modes)
-    return xf
+    mx, my, mz, mt = modes
+    xf = truncate_rfft(jnp.fft.rfft(x.astype(jnp.float32), axis=TDIM), TDIM, mt)
+    xf = truncate_full(jnp.fft.fft(xf, axis=ZDIM), ZDIM, mz)
+    xf = truncate_full(jnp.fft.fft(xf, axis=YDIM), YDIM, my)
+    xf = jnp.fft.fft(xf, axis=XDIM)
+    return truncate_full(xf, XDIM, mx) if trunc_x else xf
 
 
 def serial_adjoint(
@@ -121,20 +100,20 @@ def serial_adjoint(
     grid: Sequence[int],
     out_dtype=jnp.float32,
     *,
-    pre_padded: bool = False,
+    pad_x: bool = True,
 ) -> jax.Array:
-    """Zero-pad then inverse transform; grid is the real-space (nx,ny,nz,nt).
+    """Mirror of ``serial_forward``: each S^T right before its own inverse
+    FFT (x, y, z, then the irFFT over t); grid is the real-space
+    (nx,ny,nz,nt). The per-dim 1/n factors multiply to irfftn's.
 
-    Composed as 3-D iFFT over (x,y,z) + 1-D irFFT over t for the same
-    rank-3 XLA limit; the 1/N scaling factors multiply to irfftn's.
-
-    ``pre_padded=True`` means ``xf`` is already the full-size spectrum
-    (the fused Pallas kernel zero-fills S^T in-kernel) — skip pad_modes.
+    ``pad_x=False`` means x arrives full-size (the fused kernel zero-filled
+    S_x^T in-kernel).
     """
     nx, ny, nz, nt = grid
-    full = xf if pre_padded else pad_modes(xf, (nx, ny, nz, nt // 2 + 1))
-    full = jnp.fft.ifftn(full, axes=(XDIM, YDIM, ZDIM))
-    y = jnp.fft.irfft(full, n=nt, axis=TDIM)
+    xf = jnp.fft.ifft(pad_full(xf, XDIM, nx) if pad_x else xf, axis=XDIM)
+    xf = jnp.fft.ifft(pad_full(xf, YDIM, ny), axis=YDIM)
+    xf = jnp.fft.ifft(pad_full(xf, ZDIM, nz), axis=ZDIM)
+    y = jnp.fft.irfft(pad_rfft(xf, TDIM, nt // 2 + 1), n=nt, axis=TDIM)
     return y.astype(out_dtype)
 
 

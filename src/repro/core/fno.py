@@ -314,18 +314,19 @@ def _spectral_block(x, w_spec, w_b, b_b, cfg: FNOConfig, forward, adjoint,
 
 
 def _x_fused_block(forward, adjoint, x, w_spec, w_b, b_b, cfg: FNOConfig,
-                   axis, add_kept, bypass_x):
-    """A distributed block whose transforms truncate y, z and t before the
-    repartition; the fused kernel truncates (and pads) only x."""
-    c = cfg.comm_chunks
+                   add_kept, bypass_x, *mesh):
+    """A block whose transforms truncate y, z and t (on a mesh, before the
+    repartition); the fused kernel truncates (and pads) only x. ``mesh``:
+    a distributed pair's axis name(s), passed to both transforms with
+    ``cfg.comm_chunks``; empty for the serial pair."""
+    comm = {"comm_chunks": cfg.comm_chunks} if mesh else {}
     return _spectral_block(
         x, w_spec, w_b, b_b, cfg,
         lambda x, fused: forward(
-            x, cfg.modes, axis, trunc_x=not fused, comm_chunks=c
+            x, cfg.modes, *mesh, trunc_x=not fused, **comm
         ),
         lambda yf, fused: adjoint(
-            yf, cfg.grid, axis, out_dtype=cfg.dtype, pad_x=not fused,
-            comm_chunks=c,
+            yf, cfg.grid, *mesh, out_dtype=cfg.dtype, pad_x=not fused, **comm
         ),
         (cfg.grid[0], None, None), add_kept=add_kept, bypass_x=bypass_x,
     )
@@ -338,24 +339,17 @@ def _x_fused_block(forward, adjoint, x, w_spec, w_b, b_b, cfg: FNOConfig,
 def fno_block(x, w_spec, w_b, b_b, cfg: FNOConfig, *, add_kept=None, bypass_x=None):
     """Serial FNO block: irfftn(pad(W . trunc(rfftn(x)))) + bypass, GELU.
 
-    With ``use_pallas`` the S / W· / S^T epilogue happens inside the fused
-    kernel, so the FFT layer neither truncates nor pads — the mode tensor
-    crosses HBM once instead of four times.
+    The transforms truncate each dim right after its own FFT (and pad it
+    right before its own inverse). With ``use_pallas`` the fused kernel
+    does S_x / W· / S_x^T, so x crosses HBM at full size only there.
 
     Deep-split serving (``fno_forward_deep_split``) passes ``add_kept``, a
     cached kept-mode contribution summed into the spectral output before
     the inverse transform, and ``bypass_x``, the full activation the 1x1
     bypass runs on when ``x`` is only the dynamic remainder.
     """
-    nx, ny, nz, nt = cfg.grid
-    return _spectral_block(
-        x, w_spec, w_b, b_b, cfg,
-        lambda x, fused: dfft.serial_forward(x, cfg.modes, truncate=not fused),
-        lambda yf, fused: dfft.serial_adjoint(
-            yf, cfg.grid, out_dtype=cfg.dtype, pre_padded=fused
-        ),
-        (nx, ny, nz), nt // 2 + 1, add_kept=add_kept, bypass_x=bypass_x,
-    )
+    return _x_fused_block(dfft.serial_forward, dfft.serial_adjoint, x, w_spec,
+                          w_b, b_b, cfg, add_kept, bypass_x)
 
 
 def _run_blocks(params: dict, h: jax.Array, cfg: FNOConfig, block_apply):
@@ -508,7 +502,7 @@ def fno_block_dist(x, w_spec, w_b, b_b, cfg: FNOConfig, axis_name: str,
     fall under the ``fft_fwd`` and ``fft_inv`` scopes.
     """
     return _x_fused_block(dfft.dist_forward, dfft.dist_adjoint, x, w_spec,
-                          w_b, b_b, cfg, axis_name, add_kept, bypass_x)
+                          w_b, b_b, cfg, add_kept, bypass_x, axis_name)
 
 
 def fno_block_dist_31(x, w_spec, w_b, b_b, cfg: FNOConfig, axis_name: str,
@@ -533,7 +527,7 @@ def fno_block_dist_eager(x, w_spec, w_b, b_b, cfg: FNOConfig, axis_name: str,
                          *, add_kept=None, bypass_x=None):
     """Beyond-paper: per-dim eager truncation (bit-equivalent, cheaper FFTs)."""
     return _x_fused_block(dfft.dist_forward_eager, dfft.dist_adjoint_eager, x,
-                          w_spec, w_b, b_b, cfg, axis_name, add_kept, bypass_x)
+                          w_spec, w_b, b_b, cfg, add_kept, bypass_x, axis_name)
 
 
 def fno_block_dist_2d(x, w_spec, w_b, b_b, cfg: FNOConfig, axis_names,
@@ -541,14 +535,14 @@ def fno_block_dist_2d(x, w_spec, w_b, b_b, cfg: FNOConfig, axis_names,
     """2-D pencil block: x sharded along both x and y, spectral weights
     sharded along k_y x k_z (matching dist_forward_2d's output layout)."""
     return _x_fused_block(dfft.dist_forward_2d, dfft.dist_adjoint_2d, x,
-                          w_spec, w_b, b_b, cfg, axis_names, add_kept, bypass_x)
+                          w_spec, w_b, b_b, cfg, add_kept, bypass_x, axis_names)
 
 
 def fno_block_dist_2d_eager(x, w_spec, w_b, b_b, cfg: FNOConfig, axis_names,
                             *, add_kept=None, bypass_x=None):
     """2-D pencil block with per-dim eager truncation."""
     return _x_fused_block(dfft.dist_forward_2d_eager, dfft.dist_adjoint_2d_eager,
-                          x, w_spec, w_b, b_b, cfg, axis_names, add_kept, bypass_x)
+                          x, w_spec, w_b, b_b, cfg, add_kept, bypass_x, axis_names)
 
 
 def _fno_forward_dist_impl(params, x, cfg, axis_name, block_fn):

@@ -1,10 +1,13 @@
 """Truncated-FFT operator properties (the paper's S and F operators)."""
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 from hypothesis import given, settings, strategies as st
 
-from repro.core import dfft
+from repro.core import dfft, fno
 
 
 def _rand_complex(key, shape):
@@ -82,3 +85,93 @@ def test_forward_matches_numpy_oracle():
     sel = sel[:, :, :, :, np.r_[0:mz, 8 - mz : 8], :]
     sel = sel[:, :, :, :, :, :mt]
     np.testing.assert_allclose(got, sel, rtol=1e-4, atol=1e-4)
+
+
+def _np_corners(full, modes, trunc_x):
+    """Corner selection on a numpy rfftn spectrum ([:m] + [-m:] per full dim,
+    x only with ``trunc_x``; the first mt rFFT bins)."""
+    mx, my, mz, mt = modes
+    for axis, m in ((2, mx), (3, my), (4, mz))[0 if trunc_x else 1:]:
+        n = full.shape[axis]
+        full = np.take(full, np.r_[0:m, n - m:n], axis=axis)
+    return full[..., :mt]
+
+
+def _np_pad_inverse(spec, grid, modes, pad_x):
+    """Zero-pad a kept-mode spectrum into the full rFFT spectrum, then
+    numpy's irfftn."""
+    nx, ny, nz, nt = grid
+    mx, my, mz, mt = modes
+    full = np.zeros(spec.shape[:2] + (nx, ny, nz, nt // 2 + 1), np.complex128)
+    idx = [np.r_[0:m, n - m:n] for n, m in ((nx, mx), (ny, my), (nz, mz))]
+    if not pad_x:
+        idx[0] = np.arange(nx)
+    full[np.ix_(range(spec.shape[0]), range(spec.shape[1]), *idx, range(mt))] = spec
+    return np.fft.irfftn(full, s=grid, axes=(2, 3, 4, 5))
+
+
+@pytest.mark.parametrize("x_in_kernel", [False, True])
+@pytest.mark.parametrize(
+    "grid, modes",
+    [
+        ((8, 6, 5, 9), (4, 3, 2, 5)),     # 2m == n on x and y; odd z, odd t, all bins
+        ((7, 9, 6, 12), (2, 3, 3, 4)),    # odd x and y; 2m == n on z
+        ((16, 8, 8, 8), (5, 2, 4, 5)),    # every t bin incl. Nyquist; 2m == n on z
+    ],
+)
+def test_serial_pair_matches_numpy(grid, modes, x_in_kernel):
+    """serial_forward (trunc_x both ways) == numpy rfftn + corner selection;
+    serial_adjoint (pad_x both ways) == numpy zero-pad + irfftn; with x left
+    to the kernel, the pair is still adjoint in the real pairing."""
+    rng = np.random.default_rng(sum(grid) + sum(modes))
+    x = rng.normal(size=(2, 3) + grid).astype(np.float32)
+    got = np.asarray(dfft.serial_forward(jnp.asarray(x), modes, trunc_x=not x_in_kernel))
+    want = _np_corners(np.fft.rfftn(x.astype(np.float64), axes=(2, 3, 4, 5)),
+                       modes, not x_in_kernel)
+    assert got.shape == want.shape
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5 * np.abs(want).max())
+
+    y = (rng.normal(size=got.shape) + 1j * rng.normal(size=got.shape)).astype(np.complex64)
+    back = np.asarray(dfft.serial_adjoint(jnp.asarray(y), grid, pad_x=not x_in_kernel))
+    want_back = _np_pad_inverse(y.astype(np.complex128), grid, modes, not x_in_kernel)
+    np.testing.assert_allclose(back, want_back, rtol=1e-5,
+                               atol=1e-5 * np.abs(want_back).max())
+
+    if x_in_kernel:
+        # <x, A y>_R == Re <W F x, y>_C: A is F's real-pairing adjoint up to
+        # 1/N and the rFFT half spectrum (weight 2 off the DC/Nyquist bins)
+        nx, ny, nz, nt = grid
+        wt = np.full(modes[-1], 2.0)
+        wt[0] = 1.0
+        if nt % 2 == 0 and modes[-1] == nt // 2 + 1:
+            wt[-1] = 1.0
+        lhs = np.vdot(x.astype(np.float64), back.astype(np.float64))
+        rhs = np.vdot(got * wt / (nx * ny * nz * nt), y).real
+        np.testing.assert_allclose(lhs, rhs, rtol=1e-4)
+
+
+_FFT_OP = re.compile(r"stablehlo\.fft %\S+, type =\s+(\w+),.*?: \(tensor<([\dx]+)x\w+")
+
+
+@pytest.mark.parametrize("use_pallas", [False, True])
+def test_fno_block_ffts_run_on_truncated_spectra(use_pallas):
+    """Every FFT of a serial block other than the t rFFT / irFFT runs on an
+    operand already cut to m_t bins, so none sees the full spectrum (sizes
+    chosen so m_t and nt//2+1 are each no other dim's)."""
+    grid, modes, width = (20, 12, 8, 26), (3, 2, 3, 5), 4
+    cfg = fno.FNOConfig(grid=grid, modes=modes, width=width, use_pallas=use_pallas)
+    mt, n_bins = modes[-1], grid[-1] // 2 + 1
+
+    def s(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype)
+
+    text = jax.jit(lambda x, w, wb, bb: fno.fno_block(x, w, wb, bb, cfg)).lower(
+        s((1, width) + grid), s((width, width) + cfg.mode_shape, jnp.complex64),
+        s((width, width)), s((width,)),
+    ).as_text()
+    ffts = [(kind, [int(d) for d in dims.split("x")])
+            for kind, dims in _FFT_OP.findall(text)]
+    assert sorted(k for k, _ in ffts if k in ("RFFT", "IRFFT")) == ["IRFFT", "RFFT"]
+    spatial = [dims for k, dims in ffts if k in ("FFT", "IFFT")]
+    assert all(mt in dims and n_bins not in dims for dims in spatial), ffts
+    assert len(spatial) == 6, ffts  # x, y, z each way

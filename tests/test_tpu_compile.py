@@ -87,9 +87,10 @@ def test_topology_is_v5e(topo):
 @pytest.mark.parametrize("kernel", ["forward", "dw"])
 def test_fused_kernel_compiles(one_chip, kernel, layout):
     """The fused truncate + mix + pad kernel and its weight gradient, at
-    width 40 on the one-chip modes: the serial layout (full spectrum in,
-    every dim truncated in the kernel) and the pencil layout (y/z arrive
-    truncated by the repartition)."""
+    width 40 on the one-chip modes: the full-spectrum layout ("serial":
+    every dim truncated in the kernel, t padded back) and the layout the
+    blocks give it ("pencil": y, z and t arrive truncated by the serial
+    transforms or the repartition)."""
     from repro.kernels.spectral_conv.kernel import (
         spectral_fused_dw, spectral_fused_pallas,
     )
