@@ -6,7 +6,7 @@ varies. The static-channel work repeated per scenario per rollout step is
 then identical: normalize + encoder prelift (cache level ``prelift``), and
 — one level deeper — the first block's static kept-mode spectra and
 weight-mixed contribution (level ``deep``, the block-input split of
-``fno_forward_deep_split``). ``GeomodelCache`` computes each level once and
+``fno_forward(..., contrib=...)``). ``GeomodelCache`` computes each level once and
 replays the stored arrays by content hash. This benchmark serves the same
 vary-wells-only ensemble cold (cache disabled) vs warm at BOTH levels over
 warm (pre-compiled) runners and reports the per-level throughput ratio —

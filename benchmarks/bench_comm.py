@@ -1,10 +1,12 @@
 """Paper §IV-C claim: truncation-before-repartition cuts communicated bytes
 per re-partition by ~160x (at the paper's 80%-per-dim truncation).
 
-We lower both schedules (paper Alg. 2 vs Grady et al. [31]) on an 8-way
-model mesh and read the actual all-to-all bytes out of the compiled HLO,
-then report the measured reduction plus the closed-form factor at both our
-and the paper's truncation levels."""
+We lower both schedules' transform pairs (paper Alg. 2 ``dfft.dist_forward``
+vs Grady et al. [31] ``dfft.dist_forward_untruncated``, each followed by
+its adjoint, as one FNO block runs them) on an 8-way model mesh and read
+the actual all-to-all bytes out of the compiled HLO, then report the
+measured reduction plus the closed-form factor at both our and the paper's
+truncation levels."""
 from __future__ import annotations
 
 import textwrap
@@ -17,21 +19,27 @@ def _measure_subprocess():
         """
         import json
         import jax, jax.numpy as jnp
-        from repro.core import FNOConfig, init_params, make_dist_forward
+        from jax.sharding import PartitionSpec as P
+        from repro.common import compat
+        from repro.core import dfft
         from repro.core.partition import make_mesh
         from repro.launch import hlo_analysis as ha
 
-        cfg = FNOConfig(grid=(32, 32, 16, 16), modes=(4, 4, 2, 3), width=8,
-                        n_blocks=1, decoder_dim=8)
-        params = jax.eval_shape(lambda k: init_params(k, cfg), jax.random.PRNGKey(0))
+        grid, modes, width = (32, 32, 16, 16), (4, 4, 2, 3), 8
         mesh = make_mesh((1, 8), ("data", "model"))
-        x = jax.ShapeDtypeStruct((1, 1, 32, 32, 16, 16), jnp.float32)
+        spec = P("data", None, "model", None, None, None)
+        x = jax.ShapeDtypeStruct((1, width) + grid, jnp.float32)
+        pairs = {
+            "paper": (dfft.dist_forward, dfft.dist_adjoint),
+            "grady31": (dfft.dist_forward_untruncated, dfft.dist_adjoint_untruncated),
+        }
         out = {}
-        for variant in ("paper", "grady31"):
-            fwd = make_dist_forward(mesh, cfg, dp_axes=("data",), variant=variant)
-            hlo = jax.jit(fwd).lower(params, x).compile().as_text()
-            st = ha.collect_collectives(hlo, 8)
-            out[variant] = st.bytes_by_kind
+        for name, (fwd, adj) in pairs.items():
+            def round_trip(h, fwd=fwd, adj=adj):
+                return adj(fwd(h, modes, "model"), grid, "model")
+            f = compat.shard_map(round_trip, mesh, (spec,), spec)
+            hlo = jax.jit(f).lower(x).compile().as_text()
+            out[name] = ha.collect_collectives(hlo, 8).bytes_by_kind
         print("RESULT" + json.dumps(out))
         """
     )

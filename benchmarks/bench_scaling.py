@@ -51,7 +51,7 @@ def _measure(p: int, mode: str, nx: int | None = None):
         """
         import json
         import jax, jax.numpy as jnp
-        from repro.core import FNOConfig, init_params, make_dist_forward, make_pipeline_forward
+        from repro.core import FNOConfig, forward_and_specs, init_params, make_pipeline_forward
         from repro.core.partition import make_mesh
         from repro.launch import hlo_analysis as ha
 
@@ -66,11 +66,11 @@ def _measure(p: int, mode: str, nx: int | None = None):
         x = jax.ShapeDtypeStruct((2, 1, nx, 128, 128, 64), jnp.float32)
         if mode == "dd":
             mesh = make_mesh((1, P), ("data", "model"))
-            fwd = make_dist_forward(mesh, cfg, dp_axes=("data",))
+            fwd, _, _ = forward_and_specs(mesh, cfg, dp_axes=("data",), model_axis="model")
         elif mode == "dd2d":
             mesh = make_mesh((1, PX, PY), ("data", "mx", "my"))
-            fwd = make_dist_forward(mesh, cfg, dp_axes=("data",),
-                                    model_axis=("mx", "my"))
+            fwd, _, _ = forward_and_specs(mesh, cfg, dp_axes=("data",),
+                                          model_axis=("mx", "my"))
         else:
             mesh = make_mesh((1, P), ("data", "model"))
             fwd = make_pipeline_forward(mesh, cfg, n_micro=2)

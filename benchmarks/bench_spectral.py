@@ -34,7 +34,7 @@ def _toy_subprocess() -> dict:
         """
         import dataclasses, json, time
         import jax, jax.numpy as jnp
-        from repro.core import FNOConfig, init_params, make_dist_forward
+        from repro.core import FNOConfig, forward_and_specs, init_params
         from repro.core.partition import make_mesh
         from repro.kernels.spectral_conv import (
             cached_weight_planes, clear_plane_cache, spectral_apply_fused,
@@ -98,8 +98,8 @@ def _toy_subprocess() -> dict:
         chunk_rows = {}
         for chunks in (1, 2, 4):
             ccfg = dataclasses.replace(cfg, comm_chunks=chunks)
-            fwd = make_dist_forward(make_mesh((1, 8), ("data", "model")),
-                                    ccfg, dp_axes=("data",))
+            fwd, _, _ = forward_and_specs(make_mesh((1, 8), ("data", "model")),
+                                          ccfg, dp_axes=("data",), model_axis="model")
             st = ha.collect_collectives(
                 jax.jit(fwd).lower(params, x).compile().as_text(), 8)
             chunk_rows[str(chunks)] = {
@@ -125,7 +125,7 @@ def _sleipner_subprocess() -> dict:
         import dataclasses, json
         import jax, jax.numpy as jnp
         from repro.configs.fno_sleipner_2d import CONFIG, MODEL_AXES, PENCIL_SHAPE
-        from repro.core import init_params, make_dist_forward
+        from repro.core import forward_and_specs, init_params
         from repro.core.partition import make_mesh
         from repro.launch import hlo_analysis as ha
 
@@ -137,8 +137,8 @@ def _sleipner_subprocess() -> dict:
                "n_blocks_full": CONFIG.n_blocks}
         for chunks in (1, 2):
             ccfg = dataclasses.replace(cfg, comm_chunks=chunks)
-            fwd = make_dist_forward(mesh, ccfg, dp_axes=("data",),
-                                    model_axis=MODEL_AXES)
+            fwd, _, _ = forward_and_specs(mesh, ccfg, dp_axes=("data",),
+                                          model_axis=MODEL_AXES)
             st = ha.collect_collectives(
                 jax.jit(fwd).lower(params, x).compile().as_text(), 32)
             out["chunks_%d" % chunks] = {

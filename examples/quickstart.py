@@ -16,8 +16,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import (
-    FNOConfig, fno_forward, init_params, make_dist_forward,
-    make_pipeline_forward, mse_loss, param_specs,
+    FNOConfig, fno_forward, forward_and_specs, init_params,
+    make_pipeline_forward, mse_loss,
 )
 from repro.core.partition import make_mesh
 from repro.train import AdamWConfig, adamw_update, init_opt_state
@@ -31,7 +31,7 @@ y = jnp.tanh(jnp.roll(x, 1, axis=2))  # synthetic target
 
 # --- serial oracle vs domain decomposition (2 data x 4 model devices) ----
 mesh = make_mesh((2, 4), ("data", "model"))
-fwd_dd = make_dist_forward(mesh, cfg, dp_axes=("data",))
+fwd_dd, _, _ = forward_and_specs(mesh, cfg, dp_axes=("data",), model_axis="model")
 out_serial = jax.jit(lambda p, x: fno_forward(p, x, cfg))(params, x)
 out_dd = jax.jit(fwd_dd)(params, x)
 np.testing.assert_allclose(np.asarray(out_dd), np.asarray(out_serial), rtol=1e-4, atol=1e-5)
@@ -43,7 +43,7 @@ print(f"domain-decomposed == serial  (max diff {float(jnp.abs(out_dd - out_seria
 # solution along BOTH x and y (two per-axis all-to-alls; spectral weights
 # sharded k_y x k_z), lifting the cap to (nx/2mx)*(ny/2my).
 mesh_2d = make_mesh((2, 2, 2), ("data", "mx", "my"))
-fwd_2d = make_dist_forward(mesh_2d, cfg, dp_axes=("data",), model_axis=("mx", "my"))
+fwd_2d, _, _ = forward_and_specs(mesh_2d, cfg, dp_axes=("data",), model_axis=("mx", "my"))
 out_2d = jax.jit(fwd_2d)(params, x)
 np.testing.assert_allclose(np.asarray(out_2d), np.asarray(out_serial), rtol=1e-4, atol=1e-5)
 print(f"2-D pencil-decomposed == serial (max diff {float(jnp.abs(out_2d - out_serial).max()):.2e})")
@@ -63,7 +63,7 @@ print(f"2-D pencil-decomposed == serial (max diff {float(jnp.abs(out_2d - out_se
 import dataclasses
 
 fused_cfg = dataclasses.replace(cfg, use_pallas=True, comm_chunks=2)
-fwd_fused = make_dist_forward(mesh, fused_cfg, dp_axes=("data",))
+fwd_fused, _, _ = forward_and_specs(mesh, fused_cfg, dp_axes=("data",), model_axis="model")
 out_fused = jax.jit(fwd_fused)(params, x)
 np.testing.assert_allclose(np.asarray(out_fused), np.asarray(out_serial), rtol=1e-4, atol=1e-5)
 print(f"fused Pallas spectral path == serial (max diff "
@@ -110,8 +110,6 @@ assert losses[-1] < losses[0], "loss should decrease"
 #       --y-store /tmp/ds/y --devices 8 --model-shards 2 2
 import tempfile
 
-from jax.sharding import PartitionSpec as P
-from repro.core.fno import input_spec
 from repro.data import ArrayStore, ShardedDatasetLoader
 from repro.launch.datagen import main as datagen
 from repro.train import init_opt_state as init_opt, make_train_step
@@ -128,16 +126,15 @@ with tempfile.TemporaryDirectory() as tmp:
 
     e2e_cfg = FNOConfig(grid=(16, 8, 8, 4), modes=(4, 2, 2, 2), width=8,
                         n_blocks=2, decoder_dim=16)
-    fwd = make_dist_forward(mesh_2d, e2e_cfg, dp_axes=("data",),
-                            model_axis=("mx", "my"))
-    spec = input_spec(("data",), ("mx", "my"))
+    fwd, spec, p_specs = forward_and_specs(mesh_2d, e2e_cfg, dp_axes=("data",),
+                                           model_axis=("mx", "my"))
     jit_step = shard_train_step(
         make_train_step(
             lambda p, b: (mse_loss(fwd(p, b["x"]), b["y"]), {}),
             AdamWConfig(lr=3e-3),
         ),
         mesh_2d,
-        param_specs(mesh_2d, ("mx", "my")),
+        p_specs,
         jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), e2e_cfg)),
         {"x": spec, "y": spec},
     )
@@ -286,7 +283,7 @@ assert len(served) == 8 and not gateway.failed
 # spectral block's STATIC kept-mode spectra and weight-mixed contribution.
 # FFT -> truncate -> mix is linear, so block 0 runs only on the dynamic
 # remainder and the cached contribution is summed straight into its
-# pre-activation (core.fno.fno_forward_deep_split) — bit-identical to
+# pre-activation (core.fno.fno_forward(..., contrib=...)) — bit-identical to
 # recomputing, but the whole static spectral prefix is off the per-tick
 # path. A fleet-shared CacheStore adds the disaggregated tier behind the
 # per-replica LRUs: replicas consult it on local miss and publish fresh

@@ -19,7 +19,7 @@ CONFIG = FNOConfig(
     decoder_dim=128,
 )
 
-# Model-parallel mesh axes for make_dist_forward(model_axis=MODEL_AXES).
+# Model-parallel mesh axes for forward_and_specs(model_axis=MODEL_AXES).
 MODEL_AXES = ("mx", "my")
 
 # Production pencil shape: 8 x-shards x 4 y-shards = 32-way model

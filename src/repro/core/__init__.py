@@ -3,8 +3,8 @@
 Public surface:
   * ``CartPartition`` / ``repartition`` — DistDL-style partition + R_{x->y}
   * ``dfft`` — distributed truncated 4-D FFT (Alg. 2 operators + adjoints)
-  * ``FNOConfig`` / ``fno_forward`` / ``make_dist_forward`` — serial oracle
-    and model-parallel FNO (paper + [31] baseline schedules)
+  * ``FNOConfig`` / ``fno_forward`` / ``forward_and_specs`` — the FNO and
+    its forward for a mesh: serial, paper Alg. 2 slabs or 2-D pencils
   * ``make_pipeline_forward`` — GPipe baseline the paper compares against
   * ``ulysses_attention`` — the repartition primitive applied to attention
 """
@@ -19,24 +19,16 @@ from repro.core.repartition import (  # noqa: F401
 from repro.core.fno import (  # noqa: F401
     FNOConfig,
     contrib_spec,
-    deep_split_forward_and_specs,
     encoder_prelift,
     fno_forward,
-    fno_forward_deep_split,
-    fno_forward_dist,
-    fno_forward_dist_2d,
-    fno_forward_split,
     forward_and_specs,
     init_params,
-    make_dist_forward,
-    make_dist_forward_deep_split,
-    make_dist_forward_split,
+    input_spec,
     mse_loss,
     param_specs,
     params_with_planes,
     params_without_planes,
     spectral_prelift,
-    split_forward_and_specs,
 )
 from repro.core.pipeline import bubble_efficiency, make_pipeline_forward  # noqa: F401
 from repro.core.ulysses import ulysses_attention  # noqa: F401

@@ -229,71 +229,6 @@ def dist_adjoint(
 
 
 # ---------------------------------------------------------------------------
-# BEYOND-PAPER schedule ("eager truncation"): truncate each dim immediately
-# after ITS OWN FFT, so later FFTs run on already-truncated tensors.
-# Truncation along dim a commutes exactly with an FFT along dim b != a, so
-# this is bit-equivalent to the paper's Alg. 2 while cutting FFT flops by
-# ~2.4x and the largest spectral intermediate by ~4x (see EXPERIMENTS §Perf).
-# Communication is identical (the repartition already moved the truncated
-# tensor in Alg. 2).
-# ---------------------------------------------------------------------------
-
-def dist_forward_eager(
-    x: jax.Array,
-    modes: Sequence[int],
-    axis_name: str,
-    *,
-    trunc_x: bool = True,
-    comm_chunks: int = 1,
-) -> jax.Array:
-    """Like dist_forward, with per-dim eager truncation."""
-    mx, my, mz, mt = modes
-
-    def body(x):
-        xf = jnp.fft.rfft(x.astype(jnp.float32), axis=TDIM)
-        xf = truncate_rfft(xf, TDIM, mt)        # 33 -> mt bins before z/y FFTs
-        xf = jnp.fft.fft(xf, axis=ZDIM)
-        xf = truncate_full(xf, ZDIM, mz)
-        xf = jnp.fft.fft(xf, axis=YDIM)
-        xf = truncate_full(xf, YDIM, my)
-        xf = repartition(xf, src=XDIM, dst=YDIM, axis_name=axis_name)
-        xf = jnp.fft.fft(xf, axis=XDIM)
-        if trunc_x:
-            xf = truncate_full(xf, XDIM, mx)
-        return xf
-
-    return _chunk_channels(body, x, comm_chunks)
-
-
-def dist_adjoint_eager(
-    xf: jax.Array,
-    grid: Sequence[int],
-    axis_name: str,
-    out_dtype=jnp.float32,
-    *,
-    pad_x: bool = True,
-    comm_chunks: int = 1,
-) -> jax.Array:
-    """Adjoint of the eager schedule: inverse FFTs run while the OTHER dims
-    are still truncated; each pad happens right before its own iFFT."""
-    nx, ny, nz, nt = grid
-
-    def body(xf):
-        xf_ = pad_full(xf, XDIM, nx) if pad_x else xf
-        xf_ = jnp.fft.ifft(xf_, axis=XDIM)
-        xf_ = repartition(xf_, src=YDIM, dst=XDIM, axis_name=axis_name)
-        xf_ = pad_full(xf_, YDIM, ny)
-        xf_ = jnp.fft.ifft(xf_, axis=YDIM)
-        xf_ = pad_full(xf_, ZDIM, nz)
-        xf_ = jnp.fft.ifft(xf_, axis=ZDIM)
-        xf_ = pad_rfft(xf_, TDIM, nt // 2 + 1)
-        y = jnp.fft.irfft(xf_, n=nt, axis=TDIM)
-        return y.astype(out_dtype)
-
-    return _chunk_channels(body, xf, comm_chunks)
-
-
-# ---------------------------------------------------------------------------
 # 2-D pencil decomposition (BEYOND-PAPER): input sharded along BOTH x and y
 # on a ("mx", "my") mesh. Algorithm 2 shards a single spatial dim, capping
 # model parallelism at nx/2mx devices; pencil decomposition lifts that cap
@@ -385,67 +320,6 @@ def dist_adjoint_2d(
     return _chunk_channels(body, xf, comm_chunks)
 
 
-def dist_forward_2d_eager(
-    x: jax.Array,
-    modes: Sequence[int],
-    axis_names: Tuple[str, str] = ("mx", "my"),
-    *,
-    trunc_x: bool = True,
-    comm_chunks: int = 1,
-) -> jax.Array:
-    """2-D pencil forward with per-dim eager truncation: t is truncated
-    before the z FFT, so the z FFT runs on an mt-deep tensor (same flop
-    saving as the 1-D eager schedule; bit-equivalent to dist_forward_2d)."""
-    ax_x, ax_y = axis_names
-    mx, my, mz, mt = modes
-
-    def body(x):
-        xf = jnp.fft.rfft(x.astype(jnp.float32), axis=TDIM)
-        xf = truncate_rfft(xf, TDIM, mt)
-        xf = jnp.fft.fft(xf, axis=ZDIM)
-        xf = truncate_full(xf, ZDIM, mz)
-        xf = repartition(xf, src=YDIM, dst=ZDIM, axis_name=ax_y)
-        xf = jnp.fft.fft(xf, axis=YDIM)
-        xf = truncate_full(xf, YDIM, my)
-        xf = repartition(xf, src=XDIM, dst=YDIM, axis_name=ax_x)
-        xf = jnp.fft.fft(xf, axis=XDIM)
-        if trunc_x:
-            xf = truncate_full(xf, XDIM, mx)
-        return xf
-
-    return _chunk_channels(body, x, comm_chunks)
-
-
-def dist_adjoint_2d_eager(
-    xf: jax.Array,
-    grid: Sequence[int],
-    axis_names: Tuple[str, str] = ("mx", "my"),
-    out_dtype=jnp.float32,
-    *,
-    pad_x: bool = True,
-    comm_chunks: int = 1,
-) -> jax.Array:
-    """Adjoint of the eager 2-D schedule: each pad happens right before its
-    own iFFT, so earlier iFFTs run on still-truncated tensors."""
-    ax_x, ax_y = axis_names
-    nx, ny, nz, nt = grid
-
-    def body(xf):
-        xf_ = pad_full(xf, XDIM, nx) if pad_x else xf
-        xf_ = jnp.fft.ifft(xf_, axis=XDIM)
-        xf_ = repartition(xf_, src=YDIM, dst=XDIM, axis_name=ax_x)
-        xf_ = pad_full(xf_, YDIM, ny)
-        xf_ = jnp.fft.ifft(xf_, axis=YDIM)
-        xf_ = repartition(xf_, src=ZDIM, dst=YDIM, axis_name=ax_y)
-        xf_ = pad_full(xf_, ZDIM, nz)
-        xf_ = jnp.fft.ifft(xf_, axis=ZDIM)
-        xf_ = pad_rfft(xf_, TDIM, nt // 2 + 1)
-        y = jnp.fft.irfft(xf_, n=nt, axis=TDIM)
-        return y.astype(out_dtype)
-
-    return _chunk_channels(body, xf, comm_chunks)
-
-
 # ---------------------------------------------------------------------------
 # Grady et al. [31] baseline schedule: repartition FIRST, truncate AFTER.
 # Communicates the full (untruncated along y/z/t) spectrum — the paper's
@@ -457,16 +331,10 @@ def dist_forward_untruncated(
     modes: Sequence[int],
     axis_name: str,
     *,
-    trunc_xzt: bool = True,
     comm_chunks: int = 1,
 ) -> jax.Array:
     """[31]-style forward: F_{yzt}, R_{x->y} (full tensor!), F_x, then S.
-
-    ``trunc_xzt=False`` leaves x/z/t untruncated for the fused Pallas
-    kernel; the sharded y dim is still truncated here (truncate_y_local
-    needs the collective, and truncation along y commutes with the
-    later in-kernel x/z/t truncation).
-    """
+    In and out as ``dist_forward``."""
     mx, my, mz, mt = modes
 
     def body(x):
@@ -476,14 +344,10 @@ def dist_forward_untruncated(
         xf = repartition(xf, src=XDIM, dst=YDIM, axis_name=axis_name)
         xf = jnp.fft.fft(xf, axis=XDIM)
         # Truncate only now (after communication).
-        if trunc_xzt:
-            xf = truncate_full(xf, XDIM, mx)   # before the y gather: less data
-            xf = truncate_y_local(xf, my, axis_name)
-            xf = truncate_full(xf, ZDIM, mz)
-            xf = truncate_rfft(xf, TDIM, mt)
-        else:
-            xf = truncate_y_local(xf, my, axis_name)
-        return xf
+        xf = truncate_full(xf, XDIM, mx)   # before the y gather: less data
+        xf = truncate_y_local(xf, my, axis_name)
+        xf = truncate_full(xf, ZDIM, mz)
+        return truncate_rfft(xf, TDIM, mt)
 
     return _chunk_channels(body, x, comm_chunks)
 
@@ -520,25 +384,17 @@ def dist_adjoint_untruncated(
     axis_name: str,
     out_dtype=jnp.float32,
     *,
-    pad_xzt: bool = True,
     comm_chunks: int = 1,
 ) -> jax.Array:
-    """[31]-style inverse: pad everything first, repartition the FULL tensor.
-
-    ``pad_xzt=False`` means x/z/t arrive already full-size (the fused
-    kernel zero-filled them); only the sharded y dim still needs its
-    collective pad.
-    """
+    """[31]-style inverse: pad everything first, repartition the FULL
+    tensor. In and out as ``dist_adjoint``."""
     nx, ny, nz, nt = grid
 
     def body(xf):
-        if pad_xzt:
-            xf_ = pad_full(xf, XDIM, nx)
-            xf_ = pad_y_local(xf_, ny, axis_name)
-            xf_ = pad_full(xf_, ZDIM, nz)
-            xf_ = pad_rfft(xf_, TDIM, nt // 2 + 1)
-        else:
-            xf_ = pad_y_local(xf, ny, axis_name)
+        xf_ = pad_full(xf, XDIM, nx)
+        xf_ = pad_y_local(xf_, ny, axis_name)
+        xf_ = pad_full(xf_, ZDIM, nz)
+        xf_ = pad_rfft(xf_, TDIM, nt // 2 + 1)
         xf_ = jnp.fft.ifft(xf_, axis=XDIM)
         xf_ = repartition(xf_, src=YDIM, dst=XDIM, axis_name=axis_name)
         xf_ = jnp.fft.ifft(xf_, axis=YDIM)

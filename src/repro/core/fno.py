@@ -1,12 +1,17 @@
 """Fourier Neural Operator — serial oracle and model-parallel (paper Alg. 1/2).
 
-Functional, pytree-parameterized. The *same parameter pytree* drives:
-  * ``fno_forward``        — single-device oracle (rfftn over all 4 dims),
-  * ``fno_forward_dist``   — paper Algorithm 1/2 (call inside shard_map,
-                             X sharded along x, spectral weights along k_y),
-  * ``fno_forward_dist_31``— Grady et al. [31] baseline schedule (truncation
-                             AFTER the repartition; communication-heavy),
-so distributed-vs-serial equivalence is testable to numerical precision.
+Functional, pytree-parameterized. One forward body, ``fno_forward``, serves
+every mesh layout and every serving prefix; the layout picks the transform
+pair its blocks run (``_layout``):
+
+  * no model axis  — the serial pair (``dfft.serial_forward``);
+  * one axis name  — paper Alg. 2: X sharded along x, spectral weights
+                     along k_y (``dfft.dist_forward``), inside shard_map;
+  * an axis pair   — the 2-D pencil: X sharded along x and y, spectral
+                     weights along k_y x k_z (``dfft.dist_forward_2d``).
+
+``forward_and_specs`` builds the forward and its layouts for a mesh, so
+distributed-vs-serial equivalence is testable to numerical precision.
 
 Architecture (paper Alg. 1): 1x1-conv encoder -> n_blocks x [spectral conv
 + 1x1 bypass, GELU] -> 2-layer decoder. Spectral weights are complex64 and
@@ -20,7 +25,8 @@ the blocks, and in each block ``fft_fwd`` (with its all-to-alls), ``mix``,
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional, Tuple
+import functools
+from typing import Callable, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -132,14 +138,61 @@ def init_params(key: jax.Array, cfg: FNOConfig) -> dict:
     }
 
 
-def param_specs(mesh: Mesh, model_axis="model", *, planes: bool = False) -> dict:
-    """PartitionSpecs: spectral weights sharded along k_y (paper Alg. 2);
-    encoder/decoder/bypass replicated (the paper's broadcast B).
+# ---------------------------------------------------------------------------
+# Mesh layouts: the one place that reads the shape of ``model_axis``.
+# ---------------------------------------------------------------------------
 
-    ``model_axis`` may be a single axis name (1-D: shard k_y), a pair
-    (2-D pencil: shard k_y by the x-mesh axis and k_z by the y-mesh axis —
-    the dims each shard lands on after the pencil forward's repartitions),
-    or None (pure data parallelism: everything replicated).
+class _Layout(NamedTuple):
+    forward: Callable    # the transform pair the blocks run
+    adjoint: Callable
+    axes: tuple          # the pair's axis arguments: () serial, (name,), (pair,)
+    x: P                 # solution tensor [b, c, x, y, z, t]
+    w: P                 # spectral weights [n_blocks, ci, co, kx, ky, kz, kt]
+    contrib: P           # cached kept-mode contribution [b, co, kx, ky, kz, kt]
+    validate: Callable   # validate(cfg, mesh): the divisibility the pair needs
+
+
+def _layout(dp_axes, model_axis) -> _Layout:
+    """The layout of ``model_axis``: None (pure data parallelism, the serial
+    pair, everything but the batch replicated), one mesh-axis name (paper
+    Alg. 2: x sharded, spectral weights along k_y) or a pair of names (the
+    2-D pencil: x and y sharded, spectral weights along k_y x k_z — the
+    dims each shard lands on after the pencil forward's repartitions). The
+    batch dim goes over ``dp_axes``."""
+    if model_axis is None:
+        return _Layout(
+            dfft.serial_forward, dfft.serial_adjoint, (),
+            P(dp_axes, None, None, None, None, None), P(),
+            P(dp_axes, None, None, None, None, None),
+            lambda cfg, mesh: None,
+        )
+    if isinstance(model_axis, (tuple, list)):
+        axes = tuple(model_axis)
+        if len(axes) != 2:
+            raise ValueError(f"expected 2 model axes, got {axes}")
+        forward, adjoint, (ax_x, ax_y) = dfft.dist_forward_2d, dfft.dist_adjoint_2d, axes
+
+        def validate(cfg, mesh):
+            cfg.validate_for_parallelism_2d(mesh.shape[ax_x], mesh.shape[ax_y])
+    else:
+        forward, adjoint, axes = dfft.dist_forward, dfft.dist_adjoint, model_axis
+        ax_x, ax_y = model_axis, None
+
+        def validate(cfg, mesh):
+            cfg.validate_for_parallelism(mesh.shape[ax_x])
+    return _Layout(
+        forward, adjoint, (axes,),
+        P(dp_axes, None, ax_x, ax_y, None, None),
+        P(None, None, None, None, ax_x, ax_y, None),
+        P(dp_axes, None, None, ax_x, ax_y, None),
+        validate,
+    )
+
+
+def param_specs(mesh: Mesh, model_axis="model", *, planes: bool = False) -> dict:
+    """PartitionSpecs: spectral weights sharded as ``model_axis``'s layout
+    says (``_layout``); encoder/decoder/bypass replicated (the paper's
+    broadcast B).
 
     ``planes=True`` describes the plane-cached params tree
     (``params_with_planes``): ``w_spec`` replaced by float32
@@ -147,14 +200,7 @@ def param_specs(mesh: Mesh, model_axis="model", *, planes: bool = False) -> dict
     unflattened, so they take the SAME spec as the complex original.
     """
     del mesh
-    if model_axis is None:
-        w_spec = P()
-    elif isinstance(model_axis, (tuple, list)):
-        ax_x, ax_y = model_axis
-        w_spec = P(None, None, None, None, ax_x, ax_y, None)
-    else:
-        # [n_blocks, ci, co, kx, ky, kz, kt] -> shard ky
-        w_spec = P(None, None, None, None, model_axis, None, None)
+    w_spec = _layout(None, model_axis).w
     if planes:
         spec_leaves = {"w_spec_re": w_spec, "w_spec_im": w_spec}
     else:
@@ -168,6 +214,24 @@ def param_specs(mesh: Mesh, model_axis="model", *, planes: bool = False) -> dict
         },
         "decoder": {"w1": P(), "b1": P(), "w2": P(), "b2": P()},
     }
+
+
+def input_spec(dp_axes, model_axis) -> P:
+    """PartitionSpec of the solution tensor [b, c, x, y, z, t]: batch over
+    the data axes, x (and y, for a pencil pair) over the model axes. The
+    single source of truth for ``forward_and_specs``' in/out layout — reuse
+    it wherever explicit in_shardings must match the shard_map'd forward.
+    ``model_axis=None`` shards the batch dim only (pure data parallelism)."""
+    return _layout(dp_axes, model_axis).x
+
+
+def contrib_spec(dp_axes, model_axis) -> P:
+    """PartitionSpec of the cached kept-mode contribution
+    [b, co, 2mx, 2my, 2mz, mt]: batch over the data axes, k_y over the
+    model axis (matching ``w_spec``'s sharding, since the contribution is a
+    per-mode product with it) — and k_z over the second axis of a pencil
+    pair. ``model_axis=None`` shards the batch dim only."""
+    return _layout(dp_axes, model_axis).contrib
 
 
 def params_with_planes(params: dict) -> dict:
@@ -281,75 +345,45 @@ def _split_lift(params: dict, pre_static: jax.Array, x_dyn: jax.Array,
     return _encoder_from_prelift(params, pre, cfg)
 
 
-def _spectral_block(x, w_spec, w_b, b_b, cfg: FNOConfig, forward, adjoint,
-                    kernel_dims, t_out=None, *, add_kept=None, bypass_x=None):
-    """One FNO block from its transform pair, each stage under its own
-    named scope (``fft_fwd``, ``mix``, ``fft_inv``, ``bypass``), so that
-    a profile gives every op of a block to a stage.
+def fno_block(x, w_spec, w_b, b_b, cfg: FNOConfig, *, model_axis=None,
+              add_kept=None, bypass_x=None):
+    """One FNO block: adjoint(pad(W . trunc(forward(x)))) + bypass, GELU,
+    on the transform pair of ``model_axis``'s layout (call inside
+    shard_map for a mesh layout). Each stage runs under its own named scope
+    (``fft_fwd`` with the pair's all-to-alls, ``mix``, ``fft_inv``,
+    ``bypass``), so that a profile gives every op of a block to a stage.
 
-    ``forward(x, fused)`` transforms and truncates; with ``fused`` it
-    leaves the dims the fused kernel truncates itself (``kernel_dims``,
-    and t when ``t_out`` is given) at full size, and ``adjoint(yf,
-    fused)`` then skips padding them. ``add_kept`` / ``bypass_x`` as in
-    ``fno_block``.
+    The transforms truncate y, z and t (on a mesh, before the
+    repartition); with ``use_pallas`` the fused kernel does S_x / W· / S_x^T,
+    so x crosses HBM at full size only there.
+
+    The deep-cache forward passes ``add_kept``, a cached kept-mode
+    contribution (on a mesh, this shard's modes, as ``contrib_spec``
+    places them) summed into the spectral output before the inverse
+    transform, and ``bypass_x``, the full activation the 1x1 bypass runs
+    on when ``x`` is only the dynamic remainder.
     """
+    lay = _layout(None, model_axis)
+    comm = {"comm_chunks": cfg.comm_chunks} if lay.axes else {}
+    fused = cfg.use_pallas
+    kernel_dims = (cfg.grid[0], None, None)
     with jax.named_scope("fft_fwd"):
-        xf = forward(x, cfg.use_pallas)
+        xf = lay.forward(x, cfg.modes, *lay.axes, trunc_x=not fused, **comm)
     with jax.named_scope("mix"):
-        if not cfg.use_pallas:
+        if not fused:
             yf = spectral_apply(xf, w_spec, use_pallas=False)
             if add_kept is not None:
                 yf = yf + add_kept.astype(yf.dtype)
         elif add_kept is None:
-            yf = spectral_apply_fused(xf, w_spec, kernel_dims, t_out=t_out)
+            yf = spectral_apply_fused(xf, w_spec, kernel_dims)
         else:
-            yf = spectral_apply_fused_add(
-                xf, w_spec, add_kept, kernel_dims, t_out=t_out
-            )
+            yf = spectral_apply_fused_add(xf, w_spec, add_kept, kernel_dims)
     with jax.named_scope("fft_inv"):
-        y = adjoint(yf, cfg.use_pallas)
+        y = lay.adjoint(yf, cfg.grid, *lay.axes, out_dtype=cfg.dtype,
+                        pad_x=not fused, **comm)
     with jax.named_scope("bypass"):
         xb = x if bypass_x is None else bypass_x
         return jax.nn.gelu(y + _bypass(xb, w_b, b_b))
-
-
-def _x_fused_block(forward, adjoint, x, w_spec, w_b, b_b, cfg: FNOConfig,
-                   add_kept, bypass_x, *mesh):
-    """A block whose transforms truncate y, z and t (on a mesh, before the
-    repartition); the fused kernel truncates (and pads) only x. ``mesh``:
-    a distributed pair's axis name(s), passed to both transforms with
-    ``cfg.comm_chunks``; empty for the serial pair."""
-    comm = {"comm_chunks": cfg.comm_chunks} if mesh else {}
-    return _spectral_block(
-        x, w_spec, w_b, b_b, cfg,
-        lambda x, fused: forward(
-            x, cfg.modes, *mesh, trunc_x=not fused, **comm
-        ),
-        lambda yf, fused: adjoint(
-            yf, cfg.grid, *mesh, out_dtype=cfg.dtype, pad_x=not fused, **comm
-        ),
-        (cfg.grid[0], None, None), add_kept=add_kept, bypass_x=bypass_x,
-    )
-
-
-# ---------------------------------------------------------------------------
-# Serial oracle.
-# ---------------------------------------------------------------------------
-
-def fno_block(x, w_spec, w_b, b_b, cfg: FNOConfig, *, add_kept=None, bypass_x=None):
-    """Serial FNO block: irfftn(pad(W . trunc(rfftn(x)))) + bypass, GELU.
-
-    The transforms truncate each dim right after its own FFT (and pad it
-    right before its own inverse). With ``use_pallas`` the fused kernel
-    does S_x / W· / S_x^T, so x crosses HBM at full size only there.
-
-    Deep-split serving (``fno_forward_deep_split``) passes ``add_kept``, a
-    cached kept-mode contribution summed into the spectral output before
-    the inverse transform, and ``bypass_x``, the full activation the 1x1
-    bypass runs on when ``x`` is only the dynamic remainder.
-    """
-    return _x_fused_block(dfft.serial_forward, dfft.serial_adjoint, x, w_spec,
-                          w_b, b_b, cfg, add_kept, bypass_x)
 
 
 def _run_blocks(params: dict, h: jax.Array, cfg: FNOConfig, block_apply):
@@ -366,33 +400,59 @@ def _run_blocks(params: dict, h: jax.Array, cfg: FNOConfig, block_apply):
     return _decoder(params, h, cfg)
 
 
-def fno_forward(params: dict, x: jax.Array, cfg: FNOConfig) -> jax.Array:
-    """Single-device forward. x: [b, c_in, nx, ny, nz, nt] -> [b, c_out, ...]."""
-    h = _encoder(params, x, cfg)
-    return _run_blocks(
-        params, h, cfg,
-        lambda h, blk: fno_block(h, _block_weights(blk), blk["w_bypass"], blk["b_bypass"], cfg),
-    )
-
-
-def fno_forward_split(
-    params: dict, pre_static: jax.Array, x_dyn: jax.Array, cfg: FNOConfig, n_static: int
+def fno_forward(
+    params: dict,
+    x: jax.Array,
+    cfg: FNOConfig,
+    *,
+    model_axis=None,
+    pre_static: Optional[jax.Array] = None,
+    n_static: int = 0,
+    contrib: Optional[jax.Array] = None,
 ) -> jax.Array:
-    """Single-device forward from a precomputed static-channel prelift.
+    """The FNO forward. x: [b, c_in, nx, ny, nz, nt] -> [b, c_out, ...].
+
+    ``model_axis``: the layout (``_layout``) whose transform pair the
+    blocks run; a mesh layout is called inside shard_map
+    (``forward_and_specs``), on this shard's part of every operand.
+    Encoder and decoder weights are replicated (the paper's broadcast B) and
+    contract channels only, so they need no communication (paper Alg. 1).
 
     ``pre_static``: [b, width, ...] — the cached partial lift of the first
     ``n_static`` input channels (``encoder_prelift`` over the NORMALIZED
-    static channels). ``x_dyn``: [b, in_channels - n_static, ...] — the
-    normalized dynamic channels, lifted here. Equal to ``fno_forward`` on
-    the concatenated input up to float-summation order (the cold and warm
-    cache paths both go through THIS function, so they are bit-identical
-    to each other).
+    static channels); ``x`` then holds only the normalized dynamic
+    channels [b, in_channels - n_static, ...], lifted here. Equal to the
+    forward on the concatenated input up to float-summation order.
+
+    ``contrib`` (with ``pre_static``): [b, width, 2mx, 2my, 2mz, mt]
+    complex — block 0's kept-mode static contribution ``W_0 . S(h_static)``
+    (``spectral_prelift``). Block 0 then runs on the dynamic REMAINDER
+    ``h - h_static``, its static term summed in before the inverse
+    transform, and the remaining blocks run unchanged.
+
+    Cold and warm cache paths feed identical host-computed operands into
+    the same program, so they are bit-identical to each other.
     """
-    h = _split_lift(params, pre_static, x_dyn, cfg, n_static)
-    return _run_blocks(
-        params, h, cfg,
-        lambda h, blk: fno_block(h, _block_weights(blk), blk["w_bypass"], blk["b_bypass"], cfg),
-    )
+    ck = None if contrib is None else contrib.astype(jnp.complex64)
+
+    def block(h, blk, **kw):
+        return fno_block(h, _block_weights(blk), blk["w_bypass"], blk["b_bypass"],
+                         cfg, model_axis=model_axis, **kw)
+
+    if pre_static is None:
+        h = _encoder(params, x, cfg)
+    else:
+        h = _split_lift(params, pre_static, x, cfg, n_static)
+    if ck is not None:
+        with jax.named_scope("encoder"):
+            h_static = _encoder_from_prelift(params, pre_static.astype(cfg.dtype), cfg)
+            h_rem = h - h_static
+        blocks = params["blocks"]
+        with jax.named_scope("blocks"):
+            blk0 = jax.tree.map(lambda a: a[0], blocks)
+            h = block(h_rem, blk0, add_kept=ck, bypass_x=h)
+            params = {**params, "blocks": jax.tree.map(lambda a: a[1:], blocks)}
+    return _run_blocks(params, h, cfg, block)
 
 
 def spectral_prelift(params: dict, pre_static: jax.Array, cfg: FNOConfig, *, block: int = 0):
@@ -405,8 +465,8 @@ def spectral_prelift(params: dict, pre_static: jax.Array, cfg: FNOConfig, *, blo
     ``W . S(h_rem)  +  W . S(h_static)`` — and the second term (and its
     spectrum) can be cached alongside the prelift and summed into the
     dynamic remainder's pre-activation on every warm request
-    (``fno_forward_deep_split``). The nonlinearity after block 0 stops the
-    split from going deeper.
+    (``fno_forward(..., contrib=...)``). The nonlinearity after block 0
+    stops the split from going deeper.
 
     ``pre_static``: [b, width, nx, ny, nz, nt] (or unbatched [width, ...]).
     Returns ``(spectra, contribution)``: the truncated kept-mode spectrum
@@ -426,478 +486,65 @@ def spectral_prelift(params: dict, pre_static: jax.Array, cfg: FNOConfig, *, blo
     return spectra, contrib
 
 
-def _fno_forward_deep_impl(params, pre_static, x_dyn, cfg, n_static, block_first, block_rest):
-    """Shared deep-split body: rebuild the full first hidden state, run
-    block 0 on the dynamic REMAINDER ``h - h_static`` (its static kept-mode
-    term arrives precomputed via ``block_first``'s closure), then the
-    remaining blocks unchanged."""
-    h_full = _split_lift(params, pre_static, x_dyn, cfg, n_static)
-    with jax.named_scope("encoder"):
-        h_static = _encoder_from_prelift(params, pre_static.astype(cfg.dtype), cfg)
-        h_rem = h_full - h_static
-    blocks = params["blocks"]
-    with jax.named_scope("blocks"):
-        blk0 = jax.tree.map(lambda a: a[0], blocks)
-        h = block_first(h_rem, blk0, h_full)
-        rest = {**params, "blocks": jax.tree.map(lambda a: a[1:], blocks)}
-    return _run_blocks(rest, h, cfg, block_rest)
-
-
-def fno_forward_deep_split(
-    params: dict,
-    contrib: jax.Array,
-    pre_static: jax.Array,
-    x_dyn: jax.Array,
-    cfg: FNOConfig,
-    n_static: int,
-) -> jax.Array:
-    """Single-device forward from a cached prelift AND a cached first-block
-    static contribution (``spectral_prelift``).
-
-    ``contrib``: [b, width, 2mx, 2my, 2mz, mt] complex — the kept-mode
-    static contribution ``W_0 . S(h_static)``. Mathematically equal to
-    ``fno_forward_split`` (hence ``fno_forward``) up to float-summation
-    order; cold and warm cache paths both go through THIS function with
-    identical host-computed operands, so they are bit-identical to each
-    other.
-    """
-    ck = contrib.astype(jnp.complex64)
-
-    def first(h_rem, blk, h_full):
-        return fno_block(
-            h_rem, _block_weights(blk), blk["w_bypass"], blk["b_bypass"], cfg,
-            add_kept=ck, bypass_x=h_full,
-        )
-
-    def rest(h, blk):
-        return fno_block(
-            h, _block_weights(blk), blk["w_bypass"], blk["b_bypass"], cfg
-        )
-
-    return _fno_forward_deep_impl(
-        params, pre_static, x_dyn, cfg, n_static, first, rest
-    )
-
-
-# ---------------------------------------------------------------------------
-# Distributed forward (paper Algorithm 1 + 2). Call INSIDE shard_map with:
-#   x       sharded P(dp_axes, None, model_axis, None, None, None)
-#   w_spec  sharded P(None, None, None, None, model_axis, None, None)
-#   everything else replicated.
-# ---------------------------------------------------------------------------
-
-def fno_block_dist(x, w_spec, w_b, b_b, cfg: FNOConfig, axis_name: str,
-                   *, add_kept=None, bypass_x=None):
-    """Paper Alg. 2: local F/S over yzt, R_{x->y}, F/S over x, local spectral
-    multiply (weights pre-sharded along k_y), adjoint path back.
-
-    Fused path: y/z/t are truncated before the repartition as always (the
-    paper's comm optimization), but S_x / S_x^T move into the kernel —
-    the only dims still full-size at the kernel are the post-repartition
-    x extent, exactly the three extra HBM passes the fusion removes.
-
-    ``add_kept`` is the LOCAL shard of a cached kept-mode contribution
-    ([b, co, 2mx, 2my/P, 2mz, mt] — same k_y sharding as ``w_spec``, see
-    ``contrib_spec``); ``bypass_x`` as in ``fno_block``. The all-to-alls
-    fall under the ``fft_fwd`` and ``fft_inv`` scopes.
-    """
-    return _x_fused_block(dfft.dist_forward, dfft.dist_adjoint, x, w_spec,
-                          w_b, b_b, cfg, add_kept, bypass_x, axis_name)
-
-
-def fno_block_dist_31(x, w_spec, w_b, b_b, cfg: FNOConfig, axis_name: str,
-                      *, add_kept=None, bypass_x=None):
-    """Grady et al. [31] schedule: repartition the UNtruncated spectrum."""
-    nx, ny, nz, nt = cfg.grid
-    c = cfg.comm_chunks
-    return _spectral_block(
-        x, w_spec, w_b, b_b, cfg,
-        lambda x, fused: dfft.dist_forward_untruncated(
-            x, cfg.modes, axis_name, trunc_xzt=not fused, comm_chunks=c
-        ),
-        lambda yf, fused: dfft.dist_adjoint_untruncated(
-            yf, cfg.grid, axis_name, out_dtype=cfg.dtype, pad_xzt=not fused,
-            comm_chunks=c,
-        ),
-        (nx, None, nz), nt // 2 + 1, add_kept=add_kept, bypass_x=bypass_x,
-    )
-
-
-def fno_block_dist_eager(x, w_spec, w_b, b_b, cfg: FNOConfig, axis_name: str,
-                         *, add_kept=None, bypass_x=None):
-    """Beyond-paper: per-dim eager truncation (bit-equivalent, cheaper FFTs)."""
-    return _x_fused_block(dfft.dist_forward_eager, dfft.dist_adjoint_eager, x,
-                          w_spec, w_b, b_b, cfg, add_kept, bypass_x, axis_name)
-
-
-def fno_block_dist_2d(x, w_spec, w_b, b_b, cfg: FNOConfig, axis_names,
-                      *, add_kept=None, bypass_x=None):
-    """2-D pencil block: x sharded along both x and y, spectral weights
-    sharded along k_y x k_z (matching dist_forward_2d's output layout)."""
-    return _x_fused_block(dfft.dist_forward_2d, dfft.dist_adjoint_2d, x,
-                          w_spec, w_b, b_b, cfg, add_kept, bypass_x, axis_names)
-
-
-def fno_block_dist_2d_eager(x, w_spec, w_b, b_b, cfg: FNOConfig, axis_names,
-                            *, add_kept=None, bypass_x=None):
-    """2-D pencil block with per-dim eager truncation."""
-    return _x_fused_block(dfft.dist_forward_2d_eager, dfft.dist_adjoint_2d_eager,
-                          x, w_spec, w_b, b_b, cfg, add_kept, bypass_x, axis_names)
-
-
-def _fno_forward_dist_impl(params, x, cfg, axis_name, block_fn):
-    # Encoder/decoder weights are replicated (paper's broadcast B); the
-    # convs contract channels only, so they are embarrassingly parallel
-    # over the sharded x dim (paper Alg. 1).
-    h = _encoder(params, x, cfg)
-    return _run_blocks(
-        params, h, cfg,
-        lambda h, blk: block_fn(
-            h, _block_weights(blk), blk["w_bypass"], blk["b_bypass"], cfg, axis_name
-        ),
-    )
-
-
-def _fno_forward_dist_split_impl(params, pre_static, x_dyn, cfg, n_static, axis_name, block_fn):
-    # Split-encoder distributed forward: the prelift add and the dynamic
-    # channel contraction are pointwise over the sharded spatial dims, so
-    # they need no communication — only the blocks do (as in the fused path).
-    h = _split_lift(params, pre_static, x_dyn, cfg, n_static)
-    return _run_blocks(
-        params, h, cfg,
-        lambda h, blk: block_fn(
-            h, _block_weights(blk), blk["w_bypass"], blk["b_bypass"], cfg, axis_name
-        ),
-    )
-
-
-def fno_forward_dist(params, x, cfg: FNOConfig, axis_name: str = "model"):
-    return _fno_forward_dist_impl(params, x, cfg, axis_name, fno_block_dist)
-
-
-def fno_forward_dist_31(params, x, cfg: FNOConfig, axis_name: str = "model"):
-    return _fno_forward_dist_impl(params, x, cfg, axis_name, fno_block_dist_31)
-
-
-def fno_forward_dist_eager(params, x, cfg: FNOConfig, axis_name: str = "model"):
-    return _fno_forward_dist_impl(params, x, cfg, axis_name, fno_block_dist_eager)
-
-
-def fno_forward_dist_2d(params, x, cfg: FNOConfig, axis_names=("mx", "my")):
-    return _fno_forward_dist_impl(params, x, cfg, tuple(axis_names), fno_block_dist_2d)
-
-
-def fno_forward_dist_2d_eager(params, x, cfg: FNOConfig, axis_names=("mx", "my")):
-    return _fno_forward_dist_impl(
-        params, x, cfg, tuple(axis_names), fno_block_dist_2d_eager
-    )
-
-
-_VARIANTS = {
-    "paper": fno_forward_dist,
-    "grady31": fno_forward_dist_31,
-    "eager": fno_forward_dist_eager,
-}
-
-_VARIANTS_2D = {
-    "paper": fno_forward_dist_2d,
-    "eager": fno_forward_dist_2d_eager,
-}
-
-_BLOCKS = {
-    "paper": fno_block_dist,
-    "grady31": fno_block_dist_31,
-    "eager": fno_block_dist_eager,
-}
-
-_BLOCKS_2D = {
-    "paper": fno_block_dist_2d,
-    "eager": fno_block_dist_2d_eager,
-}
-
-
-def input_spec(dp_axes, model_axis) -> P:
-    """PartitionSpec of the solution tensor [b, c, x, y, z, t]: batch over
-    the data axes, x (and y, for a pencil pair) over the model axes. The
-    single source of truth for make_dist_forward's in/out layout — reuse it
-    wherever explicit in_shardings must match the shard_map'd forward.
-    ``model_axis=None`` shards the batch dim only (pure data parallelism)."""
-    if model_axis is None:
-        return P(dp_axes, None, None, None, None, None)
-    if isinstance(model_axis, (tuple, list)):
-        ax_x, ax_y = model_axis
-        return P(dp_axes, None, ax_x, ax_y, None, None)
-    return P(dp_axes, None, model_axis, None, None, None)
-
-
-def make_dist_forward(
-    mesh: Mesh,
-    cfg: FNOConfig,
-    *,
-    dp_axes=("data",),
-    model_axis="model",
-    variant: str = "paper",
-    planes: bool = False,
-):
-    """Build the shard_map'd distributed forward for a mesh.
-
-    ``model_axis``: a single mesh-axis name shards the solution along x
-    (paper Alg. 2); a PAIR of names, e.g. ``("mx", "my")``, selects the 2-D
-    pencil decomposition (x sharded by the first axis, y by the second),
-    lifting the 1-D parallelism cap from nx/2mx to (nx/2mx)*(ny/2my).
-
-    variant: "paper" (truncate-then-repartition), "grady31" (the [31]
-    baseline, 1-D only), or "eager" (beyond-paper per-dim truncation).
-
-    ``planes=True``: the params tree carries plane-cached spectral weights
-    (``params_with_planes``) — the shard_map in_specs must match that tree.
-    """
-    if isinstance(model_axis, (tuple, list)):
-        model_axes = tuple(model_axis)
-        if len(model_axes) != 2:
-            raise ValueError(f"expected 2 model axes, got {model_axes}")
-        cfg.validate_for_parallelism_2d(*(mesh.shape[a] for a in model_axes))
-        if variant not in _VARIANTS_2D:
-            raise ValueError(
-                f"variant {variant!r} has no 2-D schedule; pick from "
-                f"{sorted(_VARIANTS_2D)}"
-            )
-        fwd = _VARIANTS_2D[variant]
-        x_spec = input_spec(dp_axes, model_axes)
-        p_specs = param_specs(mesh, model_axes, planes=planes)
-
-        def shard_fwd(params, x):
-            return fwd(params, x, cfg, model_axes)
-
-    else:
-        cfg.validate_for_parallelism(mesh.shape[model_axis])
-        fwd = _VARIANTS[variant]
-        x_spec = input_spec(dp_axes, model_axis)
-        p_specs = param_specs(mesh, model_axis, planes=planes)
-
-        def shard_fwd(params, x):
-            return fwd(params, x, cfg, model_axis)
-
-    return compat.shard_map(
-        shard_fwd, mesh, (p_specs, x_spec), x_spec
-    )
-
-
-def make_dist_forward_split(
-    mesh: Mesh,
-    cfg: FNOConfig,
-    n_static: int,
-    *,
-    dp_axes=("data",),
-    model_axis="model",
-    variant: str = "paper",
-    planes: bool = False,
-):
-    """shard_map'd distributed forward taking (params, pre_static, x_dyn).
-
-    ``pre_static`` [b, width, ...] and ``x_dyn`` [b, c_dyn, ...] share the
-    solution tensor's layout (``input_spec``): the channel dim is never
-    sharded, so the same spec covers both. See ``fno_forward_split``.
-    """
-    if isinstance(model_axis, (tuple, list)):
-        model_axes = tuple(model_axis)
-        if len(model_axes) != 2:
-            raise ValueError(f"expected 2 model axes, got {model_axes}")
-        cfg.validate_for_parallelism_2d(*(mesh.shape[a] for a in model_axes))
-        if variant not in _BLOCKS_2D:
-            raise ValueError(
-                f"variant {variant!r} has no 2-D schedule; pick from "
-                f"{sorted(_BLOCKS_2D)}"
-            )
-        block_fn, axis = _BLOCKS_2D[variant], model_axes
-        x_spec = input_spec(dp_axes, model_axes)
-        p_specs = param_specs(mesh, model_axes, planes=planes)
-    else:
-        cfg.validate_for_parallelism(mesh.shape[model_axis])
-        block_fn, axis = _BLOCKS[variant], model_axis
-        x_spec = input_spec(dp_axes, model_axis)
-        p_specs = param_specs(mesh, model_axis, planes=planes)
-
-    def shard_fwd(params, pre_static, x_dyn):
-        return _fno_forward_dist_split_impl(
-            params, pre_static, x_dyn, cfg, n_static, axis, block_fn
-        )
-
-    return compat.shard_map(
-        shard_fwd, mesh, (p_specs, x_spec, x_spec), x_spec
-    )
-
-
-def contrib_spec(dp_axes, model_axis) -> P:
-    """PartitionSpec of the cached kept-mode contribution
-    [b, co, 2mx, 2my, 2mz, mt]: batch over the data axes, k_y over the
-    model axis (matching ``w_spec``'s sharding, since the contribution is a
-    per-mode product with it) — and k_z over the second axis of a pencil
-    pair. ``model_axis=None`` shards the batch dim only."""
-    if model_axis is None:
-        return P(dp_axes, None, None, None, None, None)
-    if isinstance(model_axis, (tuple, list)):
-        ax_x, ax_y = model_axis
-        return P(dp_axes, None, None, ax_x, ax_y, None)
-    return P(dp_axes, None, None, model_axis, None, None)
-
-
-def make_dist_forward_deep_split(
-    mesh: Mesh,
-    cfg: FNOConfig,
-    n_static: int,
-    *,
-    dp_axes=("data",),
-    model_axis="model",
-    variant: str = "paper",
-    planes: bool = False,
-):
-    """shard_map'd distributed forward taking
-    ``(params, contrib, pre_static, x_dyn)``.
-
-    ``contrib`` is the GLOBAL [b, width, 2mx, 2my, 2mz, mt] kept-mode
-    static contribution (``spectral_prelift``), sharded per
-    ``contrib_spec`` so each shard holds exactly the k_y (x k_z) modes its
-    ``w_spec`` shard would have produced. See ``fno_forward_deep_split``.
-    """
-    if isinstance(model_axis, (tuple, list)):
-        model_axes = tuple(model_axis)
-        if len(model_axes) != 2:
-            raise ValueError(f"expected 2 model axes, got {model_axes}")
-        cfg.validate_for_parallelism_2d(*(mesh.shape[a] for a in model_axes))
-        if variant not in _BLOCKS_2D:
-            raise ValueError(
-                f"variant {variant!r} has no 2-D schedule; pick from "
-                f"{sorted(_BLOCKS_2D)}"
-            )
-        block_fn, axis = _BLOCKS_2D[variant], model_axes
-        x_spec = input_spec(dp_axes, model_axes)
-        c_spec = contrib_spec(dp_axes, model_axes)
-        p_specs = param_specs(mesh, model_axes, planes=planes)
-    else:
-        cfg.validate_for_parallelism(mesh.shape[model_axis])
-        block_fn, axis = _BLOCKS[variant], model_axis
-        x_spec = input_spec(dp_axes, model_axis)
-        c_spec = contrib_spec(dp_axes, model_axis)
-        p_specs = param_specs(mesh, model_axis, planes=planes)
-
-    def shard_fwd(params, contrib, pre_static, x_dyn):
-        ck = contrib.astype(jnp.complex64)
-
-        def first(h_rem, blk, h_full):
-            return block_fn(
-                h_rem, _block_weights(blk), blk["w_bypass"], blk["b_bypass"],
-                cfg, axis, add_kept=ck, bypass_x=h_full,
-            )
-
-        def rest(h, blk):
-            return block_fn(
-                h, _block_weights(blk), blk["w_bypass"], blk["b_bypass"],
-                cfg, axis,
-            )
-
-        return _fno_forward_deep_impl(
-            params, pre_static, x_dyn, cfg, n_static, first, rest
-        )
-
-    return compat.shard_map(
-        shard_fwd, mesh, (p_specs, c_spec, x_spec, x_spec), x_spec
-    )
-
-
-def deep_split_forward_and_specs(
-    mesh: Mesh,
-    cfg: FNOConfig,
-    n_static: int,
-    *,
-    dp_axes=("data",),
-    model_axis=None,
-    variant: str = "paper",
-    planes: bool = False,
-):
-    """``split_forward_and_specs`` for the deep (first-block) split: the
-    returned ``forward(params, contrib, pre_static, x_dyn)`` additionally
-    consumes the cached kept-mode static contribution. Returns
-    ``(forward, x_spec, c_spec, p_specs)`` — ``c_spec`` is the
-    contribution's layout (``contrib_spec``)."""
-    x_spec = input_spec(dp_axes, model_axis)
-    c_spec = contrib_spec(dp_axes, model_axis)
-    p_specs = param_specs(mesh, model_axis, planes=planes)
-    if model_axis is None:
-        def forward(params, contrib, pre_static, x_dyn):
-            return fno_forward_deep_split(
-                params, contrib, pre_static, x_dyn, cfg, n_static
-            )
-    else:
-        forward = make_dist_forward_deep_split(
-            mesh, cfg, n_static, dp_axes=dp_axes, model_axis=model_axis,
-            variant=variant, planes=planes,
-        )
-    return forward, x_spec, c_spec, p_specs
-
-
-def split_forward_and_specs(
-    mesh: Mesh,
-    cfg: FNOConfig,
-    n_static: int,
-    *,
-    dp_axes=("data",),
-    model_axis=None,
-    variant: str = "paper",
-    planes: bool = False,
-):
-    """``forward_and_specs`` for the split encoder: the returned
-    ``forward(params, pre_static, x_dyn)`` consumes a precomputed (cached)
-    static-channel prelift plus the normalized dynamic channels. Layouts
-    are identical to the fused path (channel dim unsharded), so the same
-    ``x_spec`` serves both operands.
-    """
-    x_spec = input_spec(dp_axes, model_axis)
-    p_specs = param_specs(mesh, model_axis, planes=planes)
-    if model_axis is None:
-        def forward(params, pre_static, x_dyn):
-            return fno_forward_split(params, pre_static, x_dyn, cfg, n_static)
-    else:
-        forward = make_dist_forward_split(
-            mesh, cfg, n_static, dp_axes=dp_axes, model_axis=model_axis,
-            variant=variant, planes=planes,
-        )
-    return forward, x_spec, p_specs
-
-
 def forward_and_specs(
     mesh: Mesh,
     cfg: FNOConfig,
     *,
     dp_axes=("data",),
     model_axis=None,
-    variant: str = "paper",
     planes: bool = False,
+    n_static: int = 0,
+    prefix: Optional[str] = None,
 ):
     """(forward, x_spec, p_specs) for a mesh: the single source of truth for
     how an FNO batch and its params are laid out, shared by the training
-    driver and the serving runner (instead of each duplicating the
-    serial-vs-distributed branch and the spec plumbing).
+    driver and the serving runner.
 
-    ``model_axis=None`` returns the serial oracle (pure data parallelism:
-    params replicated, batch sharded over ``dp_axes``); a mesh-axis name or
-    a pair of names returns the shard_map'd distributed forward (paper
-    Alg. 2 / 2-D pencils). ``forward(params, x)`` in all cases.
+    ``model_axis`` picks the layout (``_layout``): None gives ``fno_forward``
+    itself (pure data parallelism: params replicated, batch sharded over
+    ``dp_axes``); a mesh-axis name or a pair of names gives the shard_map
+    of the same body on the layout's pair (paper Alg. 2 / 2-D pencils).
+
+    ``prefix`` picks the lift: ``forward(params, x)`` for None,
+    ``forward(params, pre_static, x_dyn)`` for ``"prelift"`` and
+    ``forward(params, contrib, pre_static, x_dyn)`` for ``"deep"``, with
+    ``n_static`` cached channels (see ``fno_forward``). ``pre_static`` and
+    ``x_dyn`` take ``x_spec`` (the channel dim is never sharded);
+    ``contrib`` takes ``contrib_spec(dp_axes, model_axis)``.
 
     ``planes=True``: specs and shard_map layouts for a plane-cached params
     tree (``params_with_planes``, serving only).
     """
-    x_spec = input_spec(dp_axes, model_axis)
+    lay = _layout(dp_axes, model_axis)
     p_specs = param_specs(mesh, model_axis, planes=planes)
-    if model_axis is None:
+    kw = {"model_axis": model_axis, "n_static": n_static}
+    if prefix is None:
         def forward(params, x):
-            return fno_forward(params, x, cfg)
+            return fno_forward(params, x, cfg, **kw)
+        cached = ()
+    elif prefix == "prelift":
+        def forward(params, pre_static, x_dyn):
+            return fno_forward(params, x_dyn, cfg, pre_static=pre_static, **kw)
+        cached = (lay.x,)
+    elif prefix == "deep":
+        def forward(params, contrib, pre_static, x_dyn):
+            return fno_forward(params, x_dyn, cfg, pre_static=pre_static,
+                               contrib=contrib, **kw)
+        cached = (lay.contrib, lay.x)
     else:
-        forward = make_dist_forward(
-            mesh, cfg, dp_axes=dp_axes, model_axis=model_axis, variant=variant,
-            planes=planes,
-        )
-    return forward, x_spec, p_specs
+        raise ValueError(f"prefix must be None, 'prelift' or 'deep', got {prefix!r}")
+    if not lay.axes:
+        return forward, lay.x, p_specs
+    lay.validate(cfg, mesh)
+
+    # jitted, this is the program ``jit_shard_fwd`` (profiles' op paths start
+    # with it), its parameters named after forward's arguments
+    @functools.wraps(forward, assigned=())
+    def shard_fwd(*args):
+        return forward(*args)
+
+    in_specs = (p_specs, *cached, lay.x)
+    return compat.shard_map(shard_fwd, mesh, in_specs, lay.x), lay.x, p_specs
 
 
 def mse_loss(pred: jax.Array, target: jax.Array) -> jax.Array:

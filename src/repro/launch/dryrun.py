@@ -167,7 +167,7 @@ def build_lm_cell(arch_id: str, shape_name: str, mesh, *, seq_shard=False, moe_a
     return jitted, args, cfg
 
 
-def build_fno_cell(fno_id: str, shape_name: str, mesh, *, variant: str = "paper", fno_dtype=None):
+def build_fno_cell(fno_id: str, shape_name: str, mesh, *, fno_dtype=None):
     cfg, shapes = get_fno(fno_id)
     if fno_dtype is not None:
         cfg = dataclasses.replace(cfg, dtype=fno_dtype)
@@ -184,25 +184,17 @@ def build_fno_cell(fno_id: str, shape_name: str, mesh, *, variant: str = "paper"
                 f"{fno_id}: pencil {pencil} does not divide mesh size {mesh.size}"
             )
         mesh = make_pencil_mesh(mesh.size // (px * py), px, py)
-        if variant not in ("paper", "eager"):
-            # grady31 has no 2-D schedule; make the substitution visible so
-            # a --variant grady31 sweep knows this cell has no baseline.
-            print(f"NOTE {fno_id}: variant {variant!r} has no 2-D schedule; "
-                  "lowering 'paper' instead")
-            variant = "paper"
     dp = dp_axes_for(mesh)
     key = jax.random.PRNGKey(0)
     abstract_params = jax.eval_shape(functools.partial(fno_lib.init_params, cfg=cfg), key)
-    p_specs = fno_lib.param_specs(mesh, model_axis)
+    fwd, x_spec, p_specs = fno_lib.forward_and_specs(mesh, cfg, dp_axes=dp, model_axis=model_axis)
     params_sh = _ns(mesh, p_specs, abstract_params)
-    fwd = fno_lib.make_dist_forward(mesh, cfg, dp_axes=dp, model_axis=model_axis, variant=variant)
     nx, ny, nz, nt = cfg.grid
-    x_spec = fno_lib.input_spec(dp, model_axis)
     x_abs = jax.ShapeDtypeStruct((bsz, cfg.in_channels, nx, ny, nz, nt), jnp.float32)
     y_abs = jax.ShapeDtypeStruct((bsz, cfg.out_channels, nx, ny, nz, nt), jnp.float32)
     x_sh = NamedSharding(mesh, _safe(x_spec, x_abs.shape, mesh))
 
-    cell_meta = {"mesh": mesh, "variant": variant}
+    cell_meta = {"mesh": mesh}
     if kind == "infer":
         jitted = jax.jit(fwd, in_shardings=(params_sh, x_sh), out_shardings=x_sh)
         return jitted, (abstract_params, x_abs), cfg, cell_meta
@@ -265,7 +257,6 @@ def run_cell(
     *,
     multi_pod: bool,
     out_dir: Optional[str],
-    variant: str = "paper",
     seq_shard: bool = False,
     fno_dtype=None,
     kv_quant: bool = False,
@@ -274,10 +265,10 @@ def run_cell(
     n_dev = mesh.size
     t0 = time.time()
     if kind == "fno":
-        jitted, args, cfg, cell_meta = build_fno_cell(arch_id, shape_name, mesh, variant=variant, fno_dtype=fno_dtype)
-        # Pencil configs re-carve the mesh and may coerce the variant;
-        # record what was actually lowered, not what was requested.
-        mesh, variant = cell_meta["mesh"], cell_meta["variant"]
+        jitted, args, cfg, cell_meta = build_fno_cell(arch_id, shape_name, mesh, fno_dtype=fno_dtype)
+        # Pencil configs re-carve the mesh; record what was actually
+        # lowered, not what was requested.
+        mesh = cell_meta["mesh"]
         n_dev = mesh.size
         shape_kind = dict((n, k) for n, _, k in get_fno(arch_id)[1])[shape_name]
         mf = model_flops_fno(cfg, [b for n, b, _ in get_fno(arch_id)[1] if n == shape_name][0], shape_kind)
@@ -302,7 +293,6 @@ def run_cell(
         "arch": arch_id,
         "shape": shape_name,
         "kind": kind,
-        "variant": variant,
         "mesh": {"shape": list(mesh.devices.shape), "axes": list(mesh.axis_names), "devices": n_dev},
         "lower_s": round(t_lower, 2),
         "compile_s": round(t_compile, 2),
@@ -333,8 +323,6 @@ def run_cell(
     if out_dir:
         os.makedirs(out_dir, exist_ok=True)
         suffix = "multipod" if multi_pod else "pod"
-        if variant != "paper":
-            suffix += f"_{variant}"
         if not seq_shard:
             suffix += "_nosp"
         path = os.path.join(out_dir, f"{arch_id}_{shape_name}_{suffix}.json")
@@ -365,7 +353,6 @@ def main():
     ap.add_argument("--both-meshes", action="store_true")
     ap.add_argument("--all", action="store_true", help="run every supported cell")
     ap.add_argument("--list", action="store_true")
-    ap.add_argument("--variant", default="paper", choices=("paper", "grady31"))
     ap.add_argument(
         "--seq-shard", action=argparse.BooleanOptionalAction, default=True,
         help="Megatron-SP activation sharding (default on; --no-seq-shard "
@@ -404,7 +391,7 @@ def main():
             try:
                 art = run_cell(
                     kind, arch, shape, multi_pod=mp, out_dir=args.out_dir,
-                    variant=args.variant, seq_shard=args.seq_shard,
+                    seq_shard=args.seq_shard,
                 )
                 print(
                     f"OK  {tag:60s} compile={art['compile_s']:7.1f}s "

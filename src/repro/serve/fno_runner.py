@@ -12,10 +12,10 @@ that surrogate through the same slot scheduler that serves LLM tokens:
     compilation — and, because XLA results are a function of the batch
     SHAPE, a request's output is bit-identical however admission order or
     slot reuse interleaves it with other traffic of the same bucket);
-  * the forward is the family's distributed one when the mesh carries model
-    axes (paper Alg. 2 / 2-D pencils) — params and batch go through the
-    same ``forward_and_specs`` layout contract the training driver uses,
-    so a checkpoint trained model-parallel serves model-parallel;
+  * the forward is the shard_map'd one when the mesh carries model axes
+    (paper Alg. 2 / 2-D pencils) — params and batch go through the same
+    ``forward_and_specs`` layout contract the training driver uses, so a
+    checkpoint trained model-parallel serves model-parallel;
   * ingress applies the store's persisted per-channel normalization (the
     exact stats training normalized with, snapshotted into the
     checkpoint's ``fno_config.json``); egress inverts the target
@@ -30,7 +30,8 @@ that surrogate through the same slot scheduler that serves LLM tokens:
     the same geomodel across thousands of scenarios, so its normalized form
     and encoder prelift are cached by content hash in a shared
     ``GeomodelCache`` (the KV-cache of PDE serving) and the per-tick
-    forward only lifts the dynamic channels (``fno_forward_split``);
+    forward only lifts the dynamic channels (``forward_and_specs``'
+    ``prefix``, named by ``cache_level``);
     ``feedback`` then produces only the DYNAMIC channels — the geomodel
     persists across rollout steps without re-normalize/re-lift. The runner
     also keys requests by content (``request_key``) so the scheduler can
@@ -58,8 +59,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.common import tracing
 from repro.core.fno import (
-    FNOConfig, deep_split_forward_and_specs, forward_and_specs, init_params,
-    params_with_planes, split_forward_and_specs,
+    FNOConfig, contrib_spec, forward_and_specs, init_params, params_with_planes,
 )
 from repro.data.loader import Normalizer
 from repro.launch.mesh import build_fno_mesh
@@ -210,9 +210,12 @@ class FNORunner:
         # the complex original is dropped — every block of every rollout
         # step reuses the same planes instead of re-splitting.
         self._planes = bool(cfg.use_pallas)
+        # the one forward this runner serves: the plain one without static
+        # channels, else the cached prefix its level names
+        self._prefix = cache_level if n_static else None
         forward, x_spec, p_specs = forward_and_specs(
             mesh, cfg, dp_axes=("data",), model_axis=model_axis,
-            planes=self._planes,
+            planes=self._planes, n_static=n_static, prefix=self._prefix,
         )
         self._n_dp = mesh.shape["data"]
         self.buckets = (
@@ -254,49 +257,30 @@ class FNORunner:
         # the COMPLEX tree, before any planes conversion) for the per-miss
         # numpy spectral prefix
         self._w0 = None
-        if n_static and cache_level == "deep":
+        if self._prefix == "deep":
             self._w0 = np.asarray(
                 jax.device_get(params["blocks"]["w_spec"][0])
             ).astype(np.complex64)
         if self._planes:
             params = params_with_planes(params)
         self.params = jax.device_put(params, ns(p_specs))
+        # the static inputs before x_dyn: pre_static [b, width, ...] shares
+        # the solution layout (channel dim unsharded), the contribution
+        # takes the spectral weights' mode sharding
+        static_specs = {
+            None: (),
+            "prelift": (x_spec,),
+            "deep": (contrib_spec(("data",), model_axis), x_spec),
+        }[self._prefix]
         # one jit; XLA specializes per bucket shape on first use
         self._forward = jax.jit(
             forward,
-            in_shardings=(ns(p_specs), self._x_sharding),
+            in_shardings=(
+                ns(p_specs), *(NamedSharding(mesh, s) for s in static_specs),
+                self._x_sharding,
+            ),
             out_shardings=self._x_sharding,
         )
-        self._forward_split = None
-        self._forward_deep = None
-        static_specs = ()
-        if n_static:
-            static_specs = (x_spec,)
-            split_fwd, _, _ = split_forward_and_specs(
-                mesh, cfg, n_static, dp_axes=("data",), model_axis=model_axis,
-                planes=self._planes,
-            )
-            # pre_static [b, width, ...] and x_dyn [b, c_dyn, ...] share the
-            # solution layout (channel dim unsharded)
-            self._forward_split = jax.jit(
-                split_fwd,
-                in_shardings=(ns(p_specs), self._x_sharding, self._x_sharding),
-                out_shardings=self._x_sharding,
-            )
-            if cache_level == "deep":
-                deep_fwd, _, c_spec, _ = deep_split_forward_and_specs(
-                    mesh, cfg, n_static, dp_axes=("data",),
-                    model_axis=model_axis, planes=self._planes,
-                )
-                static_specs = (c_spec, x_spec)
-                self._forward_deep = jax.jit(
-                    deep_fwd,
-                    in_shardings=(
-                        ns(p_specs), NamedSharding(mesh, c_spec),
-                        self._x_sharding, self._x_sharding,
-                    ),
-                    out_shardings=self._x_sharding,
-                )
         # device table of static rows (see the module docstring), LRU over
         # at most max_slots geomodel keys: the most one bucket reads. Each
         # row is placed as the activations are along the model axes and
@@ -626,14 +610,14 @@ class FNORunner:
 
     def _served_forward(self, bucket: int, static: bool = True):
         """(jitted forward, zero host batch args after params) for one
-        bucket: the deep split, the split, or the plain forward — whichever
-        ``step`` runs for this runner. ``static=False`` leaves out the
-        static args, which the device table then supplies, and gives the
-        program compiled for the host batch (``compiled_step``): traced on
-        device arrays, whose types carry their sharding, the jitted forward
-        would compile a different program, with different rounding."""
+        bucket, in the argument order of the runner's level. ``static=False``
+        leaves out the static args, which the device table then supplies,
+        and gives the program compiled for the host batch
+        (``compiled_step``): traced on device arrays, whose types carry
+        their sharding, the jitted forward would compile a different
+        program, with different rounding."""
         grid = tuple(self.cfg.grid)
-        if not self.n_static:
+        if self._prefix is None:
             return self._forward, (
                 np.zeros((bucket, self.cfg.in_channels) + grid, np.float32),
             )
@@ -642,11 +626,11 @@ class FNORunner:
         )
         if not static:
             return self.compiled_step(bucket), (xd,)
-        pre = np.zeros((bucket, self.cfg.width) + grid, np.float32)
-        if self._forward_deep is None:
-            return self._forward_split, (pre, xd)
-        ck = np.zeros((bucket, self.cfg.width) + self.cfg.mode_shape, np.complex64)
-        return self._forward_deep, (ck, pre, xd)
+        args = (np.zeros((bucket, self.cfg.width) + grid, np.float32), xd)
+        if self._prefix == "deep":
+            ck = np.zeros((bucket, self.cfg.width) + self.cfg.mode_shape, np.complex64)
+            args = (ck,) + args
+        return self._forward, args
 
     @property
     def _holds_rows(self) -> bool:
@@ -702,7 +686,7 @@ class FNORunner:
     def _static_rows(self, entry: GeomodelEntry) -> tuple:
         """The entry's rows the forward reads as static inputs, in its
         argument order."""
-        if self._forward_deep is None:
+        if self._prefix == "prelift":
             return (entry.prelift,)
         return (entry.contribution, entry.prelift)
 

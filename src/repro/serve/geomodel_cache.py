@@ -20,7 +20,7 @@ hash of the RAW static channels, as a MULTI-LEVEL entry (shallow -> deep):
     first hidden state S(GELU(prelift + b)) (``core.fno.spectral_prelift``);
   * ``contribution``  — its first-block weight mix W_0 . S(h_static), the
     term summed straight into the dynamic remainder's pre-activation by
-    ``fno_forward_deep_split``.
+    ``fno_forward(..., contrib=...)``.
 
 Each level is derived from the previous one, so the LRU may drop the DEEP
 levels of a cold entry (freeing the complex64 tensors) while keeping the

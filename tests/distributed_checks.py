@@ -18,7 +18,7 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core import (
-    FNOConfig, fno_forward, forward_and_specs, init_params, make_dist_forward,
+    FNOConfig, fno_forward, forward_and_specs, init_params,
     make_pipeline_forward, param_specs, params_with_planes,
     params_without_planes, repartition, repartition_chunked,
     ulysses_attention,
@@ -28,6 +28,11 @@ from repro.core.partition import make_mesh
 from repro.core.ulysses import _dense_attention
 
 CHECKS = []
+
+
+def dist_forward(mesh, cfg, model_axis="model", **kw):
+    """The shard_map'd forward ``forward_and_specs`` builds for a mesh."""
+    return forward_and_specs(mesh, cfg, dp_axes=("data",), model_axis=model_axis, **kw)[0]
 
 
 def check(fn):
@@ -76,13 +81,11 @@ def fno_dist_matches_serial():
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 2, 16, 16, 8, 8))
     y_ser = jax.jit(lambda p, x: fno_forward(p, x, cfg))(params, x)
     mesh = make_mesh((2, 4), ("data", "model"))
-    for variant in ("paper", "grady31"):
-        fwd = make_dist_forward(mesh, cfg, dp_axes=("data",), variant=variant)
-        y = jax.jit(fwd)(params, x)
-        np.testing.assert_allclose(np.asarray(y), np.asarray(y_ser), rtol=2e-4, atol=2e-5)
+    fwd = dist_forward(mesh, cfg)
+    y = jax.jit(fwd)(params, x)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(y_ser), rtol=2e-4, atol=2e-5)
     # gradient equivalence through the distributed path
     g_ser = jax.jit(jax.grad(lambda p: jnp.mean(fno_forward(p, x, cfg) ** 2)))(params)
-    fwd = make_dist_forward(mesh, cfg, dp_axes=("data",))
     g_dd = jax.jit(jax.grad(lambda p: jnp.mean(fwd(p, x) ** 2)))(params)
     jax.tree.map(
         lambda a, b: np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=5e-3, atol=5e-5),
@@ -99,14 +102,11 @@ def fno_dist_2d_pencil_matches_serial():
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 2, 16, 16, 8, 8))
     y_ser = jax.jit(lambda p, x: fno_forward(p, x, cfg))(params, x)
     mesh = make_mesh((2, 2, 2), ("data", "mx", "my"))
-    for variant in ("paper", "eager"):
-        fwd = make_dist_forward(mesh, cfg, dp_axes=("data",),
-                                model_axis=("mx", "my"), variant=variant)
-        y = jax.jit(fwd)(params, x)
-        np.testing.assert_allclose(np.asarray(y), np.asarray(y_ser), rtol=2e-4, atol=2e-5)
+    fwd = dist_forward(mesh, cfg, ("mx", "my"))
+    y = jax.jit(fwd)(params, x)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(y_ser), rtol=2e-4, atol=2e-5)
     # gradient equivalence through both all-to-alls
     g_ser = jax.jit(jax.grad(lambda p: jnp.mean(fno_forward(p, x, cfg) ** 2)))(params)
-    fwd = make_dist_forward(mesh, cfg, dp_axes=("data",), model_axis=("mx", "my"))
     g_dd = jax.jit(jax.grad(lambda p: jnp.mean(fwd(p, x) ** 2)))(params)
     jax.tree.map(
         lambda a, b: np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=5e-3, atol=5e-5),
@@ -300,20 +300,18 @@ def fno_comm_chunks_matches_unchunked():
     params = init_params(jax.random.PRNGKey(0), cfg)
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 2, 16, 16, 8, 8))
     mesh = make_mesh((2, 4), ("data", "model"))
-    y0 = jax.jit(make_dist_forward(mesh, cfg, dp_axes=("data",)))(params, x)
-    y2 = jax.jit(make_dist_forward(mesh, cfg_ck, dp_axes=("data",)))(params, x)
+    y0 = jax.jit(dist_forward(mesh, cfg))(params, x)
+    y2 = jax.jit(dist_forward(mesh, cfg_ck))(params, x)
     np.testing.assert_allclose(np.asarray(y2), np.asarray(y0), rtol=1e-6, atol=1e-7)
     mesh2 = make_mesh((2, 2, 2), ("data", "mx", "my"))
-    y0 = jax.jit(make_dist_forward(
-        mesh2, cfg, dp_axes=("data",), model_axis=("mx", "my")))(params, x)
-    y2 = jax.jit(make_dist_forward(
-        mesh2, cfg_ck, dp_axes=("data",), model_axis=("mx", "my")))(params, x)
+    y0 = jax.jit(dist_forward(mesh2, cfg, ("mx", "my")))(params, x)
+    y2 = jax.jit(dist_forward(mesh2, cfg_ck, ("mx", "my")))(params, x)
     np.testing.assert_allclose(np.asarray(y2), np.asarray(y0), rtol=1e-6, atol=1e-7)
 
 
 @check
 def fno_fused_pallas_matches_serial():
-    """The ISSUE's gate: every use_pallas=True dist variant == the UNFUSED
+    """The fused gate: every use_pallas=True dist layout == the UNFUSED
     serial oracle to <= 1e-4, gradients included (interpret-mode kernels)."""
     import dataclasses
     cfg = FNOConfig(grid=(16, 16, 8, 8), modes=(4, 4, 2, 3), width=6,
@@ -335,24 +333,20 @@ def fno_fused_pallas_matches_serial():
         g_f, g_ser,
     )
 
-    # every 1-D dist variant, fused, vs the serial oracle
+    # 1-D slabs, fused, vs the serial oracle
     mesh = make_mesh((2, 4), ("data", "model"))
-    for variant in ("paper", "eager", "grady31"):
-        fwd = make_dist_forward(mesh, cfg, dp_axes=("data",), variant=variant)
-        y = jax.jit(fwd)(params, x)
-        np.testing.assert_allclose(np.asarray(y), np.asarray(y_ser), rtol=1e-4, atol=1e-5)
+    fwd_f = dist_forward(mesh, cfg)
+    y = jax.jit(fwd_f)(params, x)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(y_ser), rtol=1e-4, atol=1e-5)
 
     # 2-D pencils, fused
     mesh2 = make_mesh((2, 2, 2), ("data", "mx", "my"))
-    for variant in ("paper", "eager"):
-        fwd = make_dist_forward(mesh2, cfg, dp_axes=("data",),
-                                model_axis=("mx", "my"), variant=variant)
-        y = jax.jit(fwd)(params, x)
-        np.testing.assert_allclose(np.asarray(y), np.asarray(y_ser), rtol=1e-4, atol=1e-5)
+    fwd_f2 = dist_forward(mesh2, cfg, ("mx", "my"))
+    y = jax.jit(fwd_f2)(params, x)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(y_ser), rtol=1e-4, atol=1e-5)
 
     # gradient gate: fused dist vs unfused dist (tight) and vs serial
-    fwd_f = make_dist_forward(mesh, cfg, dp_axes=("data",))
-    fwd_u = make_dist_forward(mesh, cfg_ref, dp_axes=("data",))
+    fwd_u = dist_forward(mesh, cfg_ref)
     g_df = jax.jit(jax.grad(lambda p: jnp.mean(fwd_f(p, x) ** 2)))(params)
     g_du = jax.jit(jax.grad(lambda p: jnp.mean(fwd_u(p, x) ** 2)))(params)
     jax.tree.map(
@@ -366,7 +360,6 @@ def fno_fused_pallas_matches_serial():
         g_df, g_ser,
     )
     # 2-D grads, fused vs serial
-    fwd_f2 = make_dist_forward(mesh2, cfg, dp_axes=("data",), model_axis=("mx", "my"))
     g_df2 = jax.jit(jax.grad(lambda p: jnp.mean(fwd_f2(p, x) ** 2)))(params)
     jax.tree.map(
         lambda a, b: np.testing.assert_allclose(
@@ -377,15 +370,13 @@ def fno_fused_pallas_matches_serial():
 
 @check
 def fno_deep_split_matches_serial():
-    """The deep block-input split — a cached first-block kept-mode static
-    contribution summed into the dynamic remainder's pre-activation — ==
-    the UNFUSED serial oracle to <= 1e-4 through every serving layout:
-    serial (unfused + fused), every 1-D dist variant, and 2-D pencils."""
+    """The cached-prefix forwards — the prelift split, and the deep
+    block-input split whose cached first-block kept-mode static
+    contribution is summed into the dynamic remainder's pre-activation —
+    == the UNFUSED serial oracle to <= 1e-4 through every serving layout:
+    serial (unfused + fused), 1-D slabs and 2-D pencils (fused)."""
     import dataclasses
-    from repro.core import (
-        encoder_prelift, fno_forward_deep_split, make_dist_forward_deep_split,
-        spectral_prelift,
-    )
+    from repro.core import encoder_prelift, spectral_prelift
 
     n_static = 1
     cfg = FNOConfig(grid=(16, 16, 8, 8), modes=(4, 4, 2, 3), width=6,
@@ -399,32 +390,23 @@ def fno_deep_split_matches_serial():
     xd = x[:, n_static:]
     pre_s = encoder_prelift(params, x[:, :n_static], cfg, slice(0, n_static))
     _, contrib = spectral_prelift(params, pre_s, cfg_ref)
+    cached = {"prelift": (pre_s,), "deep": (contrib, pre_s)}
 
-    # serial deep split: unfused, then fused Pallas
-    for c in (cfg_ref, cfg):
-        y = jax.jit(lambda p, ck, ps, xdyn, c=c: fno_forward_deep_split(
-            p, ck, ps, xdyn, c, n_static))(params, contrib, pre_s, xd)
-        np.testing.assert_allclose(
-            np.asarray(y), np.asarray(y_ser), rtol=1e-4, atol=1e-5)
-
-    # every 1-D dist variant, fused, contrib sharded along k_y
-    mesh = make_mesh((2, 4), ("data", "model"))
-    for variant in ("paper", "eager", "grady31"):
-        fwd = make_dist_forward_deep_split(
-            mesh, cfg, n_static, dp_axes=("data",), variant=variant)
-        y = jax.jit(fwd)(params, contrib, pre_s, xd)
-        np.testing.assert_allclose(
-            np.asarray(y), np.asarray(y_ser), rtol=1e-4, atol=1e-5)
-
-    # 2-D pencils, fused, contrib sharded along (k_y, k_z)
-    mesh2 = make_mesh((2, 2, 2), ("data", "mx", "my"))
-    for variant in ("paper", "eager"):
-        fwd = make_dist_forward_deep_split(
-            mesh2, cfg, n_static, dp_axes=("data",),
-            model_axis=("mx", "my"), variant=variant)
-        y = jax.jit(fwd)(params, contrib, pre_s, xd)
-        np.testing.assert_allclose(
-            np.asarray(y), np.asarray(y_ser), rtol=1e-4, atol=1e-5)
+    # serial: unfused, then fused Pallas; 1-D slabs with contrib sharded
+    # along k_y, 2-D pencils along (k_y, k_z), fused
+    layouts = [
+        (make_mesh((1,), ("data",)), None, cfg_ref),
+        (make_mesh((1,), ("data",)), None, cfg),
+        (make_mesh((2, 4), ("data", "model")), "model", cfg),
+        (make_mesh((2, 2, 2), ("data", "mx", "my")), ("mx", "my"), cfg),
+    ]
+    for mesh, model_axis, c in layouts:
+        for prefix, args in cached.items():
+            fwd = dist_forward(mesh, c, model_axis, n_static=n_static, prefix=prefix)
+            y = jax.jit(fwd)(params, *args, xd)
+            np.testing.assert_allclose(
+                np.asarray(y), np.asarray(y_ser), rtol=1e-4, atol=1e-5,
+                err_msg=f"{model_axis} {prefix} use_pallas={c.use_pallas}")
 
 
 @check
@@ -551,7 +533,7 @@ def pencil_all_to_alls_run_under_a2a_scope():
                     in_channels=2, out_channels=1, n_blocks=2, decoder_dim=8)
     params = init_params(jax.random.PRNGKey(0), cfg)
     mesh = make_mesh((2, 2, 2), ("data", "mx", "my"))
-    fwd = make_dist_forward(mesh, cfg, dp_axes=("data",), model_axis=("mx", "my"))
+    fwd = dist_forward(mesh, cfg, ("mx", "my"))
     x = jax.ShapeDtypeStruct((2, 2, 16, 16, 8, 8), jnp.float32)
     text = jax.jit(fwd).lower(params, x).compile().as_text()
     names = re.findall(r"all-to-all\(.*op_name=\"([^\"]+)\"", text)

@@ -26,8 +26,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.core import FNOConfig, init_params, make_dist_forward, mse_loss
-from repro.core.fno import input_spec, param_specs
+from repro.core import FNOConfig, forward_and_specs, init_params, mse_loss
+from repro.core.fno import input_spec
 from repro.core.partition import make_mesh
 from repro.data import ArrayStore, ShardedDatasetLoader
 from repro.train import AdamWConfig, init_opt_state, make_train_step
@@ -154,14 +154,14 @@ def sharded_train_step_consumes_loader_batches():
         grid=(NX, NY, NZ, NT), modes=(4, 2, 2, 2), width=6,
         in_channels=C, out_channels=C, n_blocks=2, decoder_dim=12,
     )
-    fwd = make_dist_forward(MESH, cfg, dp_axes=("data",), model_axis=("mx", "my"))
+    fwd, _, p_specs = forward_and_specs(MESH, cfg, dp_axes=("data",),
+                                        model_axis=("mx", "my"))
 
     def loss_fn(params, batch):
         return mse_loss(fwd(params, batch["x"]), batch["y"]), {}
 
     params = init_params(jax.random.PRNGKey(0), cfg)
     abstract = jax.eval_shape(lambda: params)
-    p_specs = param_specs(MESH, ("mx", "my"))
     step_fn = make_train_step(loss_fn, AdamWConfig(lr=2e-3), grad_accum=1)
     jit_step = shard_train_step(step_fn, MESH, p_specs, abstract, SPECS)
     opt = init_opt_state(params)

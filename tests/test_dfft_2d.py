@@ -1,5 +1,5 @@
-"""Property tests for the truncated-FFT operators: 1-D variants (paper /
-eager / grady31) and the 2-D pencil decomposition.
+"""Property tests for the truncated-FFT operators: the 1-D pairs (paper
+Alg. 2 and the Grady et al. [31] baseline) and the 2-D pencil pair.
 
 Three layers of guarantees:
   * hypothesis-driven dot-product adjoint tests  <F x, y> == <x, F^T y>
@@ -71,15 +71,13 @@ def _mesh_2d():
     return make_mesh((p, p), ("mx", "my")), p
 
 
-_VARIANTS_1D = {
+_PAIRS_1D = {
     "paper": (dfft.dist_forward, dfft.dist_adjoint),
-    "eager": (dfft.dist_forward_eager, dfft.dist_adjoint_eager),
     "grady31": (dfft.dist_forward_untruncated, dfft.dist_adjoint_untruncated),
 }
 
-_VARIANTS_2D = {
+_PAIRS_2D = {
     "paper": (dfft.dist_forward_2d, dfft.dist_adjoint_2d),
-    "eager": (dfft.dist_forward_2d_eager, dfft.dist_adjoint_2d_eager),
 }
 
 
@@ -128,16 +126,16 @@ def _check_against_serial(fwd, adj, grid, modes, seed, rtol=1e-4):
     ny=st.sampled_from([8, 12]),
     m=st.integers(1, 3),
     mt=st.integers(1, 4),
-    variant=st.sampled_from(sorted(_VARIANTS_1D)),
+    pair=st.sampled_from(sorted(_PAIRS_1D)),
 )
-def test_dist_1d_adjoint_properties(nx, ny, m, mt, variant):
+def test_dist_1d_adjoint_properties(nx, ny, m, mt, pair):
     grid = (nx, ny, 8, 8)
     modes = (min(m, nx // 2), min(m + 1, ny // 2), m, mt)
     mesh, p = _mesh_1d()
     if (2 * modes[1]) % p or nx % p:
         p = 1
         mesh = make_mesh((1,), ("model",))
-    fwd_fn, adj_fn = _VARIANTS_1D[variant]
+    fwd_fn, adj_fn = _PAIRS_1D[pair]
     x_spec = P(None, None, "model", None, None, None)
     f_spec = P(None, None, None, "model", None, None)
     fwd = jax.jit(
@@ -155,9 +153,9 @@ def test_dist_1d_adjoint_properties(nx, ny, m, mt, variant):
     nz=st.sampled_from([4, 8]),
     m=st.integers(1, 2),
     mt=st.integers(1, 3),
-    variant=st.sampled_from(sorted(_VARIANTS_2D)),
+    pair=st.sampled_from(sorted(_PAIRS_2D)),
 )
-def test_dist_2d_pencil_adjoint_properties(nx, nz, m, mt, variant):
+def test_dist_2d_pencil_adjoint_properties(nx, nz, m, mt, pair):
     grid = (nx, 8, nz, 8)
     modes = (min(m + 1, nx // 2), m, min(m, nz // 2), mt)
     mesh, p = _mesh_2d()
@@ -165,7 +163,7 @@ def test_dist_2d_pencil_adjoint_properties(nx, nz, m, mt, variant):
     if nx % p or (2 * modes[1]) % p or 8 % p or (2 * modes[2]) % p:
         p = 1
         mesh = make_mesh((1, 1), ("mx", "my"))
-    fwd_fn, adj_fn = _VARIANTS_2D[variant]
+    fwd_fn, adj_fn = _PAIRS_2D[pair]
     x_spec = P(None, None, "mx", "my", None, None)
     f_spec = P(None, None, None, "mx", "my", None)
     fwd = jax.jit(
@@ -282,21 +280,19 @@ def test_pencil_2x2_mesh_matches_serial_subprocess():
                             ((8, 16, 4, 6), (2, 3, 2, 2))):
             x = jax.random.normal(jax.random.PRNGKey(0), (2, 2) + grid)
             ref = dfft.serial_forward(x, modes)
-            for fwd_fn, adj_fn in (
-                (dfft.dist_forward_2d, dfft.dist_adjoint_2d),
-                (dfft.dist_forward_2d_eager, dfft.dist_adjoint_2d_eager),
-            ):
-                fwd = jax.jit(compat.shard_map(
-                    lambda a: fwd_fn(a, modes, ("mx", "my")), mesh, (x_spec,), f_spec))
-                adj = jax.jit(compat.shard_map(
-                    lambda a: adj_fn(a, grid, ("mx", "my")), mesh, (f_spec,), x_spec))
-                got = fwd(x)
-                rel = float(jnp.max(jnp.abs(got - ref)) / jnp.max(jnp.abs(ref)))
-                assert rel <= 1e-4, (fwd_fn.__name__, grid, rel)
-                back_ref = dfft.serial_adjoint(ref, grid)
-                back = adj(got)
-                rel_a = float(jnp.max(jnp.abs(back - back_ref)) / jnp.max(jnp.abs(back_ref)))
-                assert rel_a <= 1e-4, (adj_fn.__name__, grid, rel_a)
+            fwd = jax.jit(compat.shard_map(
+                lambda a: dfft.dist_forward_2d(a, modes, ("mx", "my")),
+                mesh, (x_spec,), f_spec))
+            adj = jax.jit(compat.shard_map(
+                lambda a: dfft.dist_adjoint_2d(a, grid, ("mx", "my")),
+                mesh, (f_spec,), x_spec))
+            got = fwd(x)
+            rel = float(jnp.max(jnp.abs(got - ref)) / jnp.max(jnp.abs(ref)))
+            assert rel <= 1e-4, ("dist_forward_2d", grid, rel)
+            back_ref = dfft.serial_adjoint(ref, grid)
+            back = adj(got)
+            rel_a = float(jnp.max(jnp.abs(back - back_ref)) / jnp.max(jnp.abs(back_ref)))
+            assert rel_a <= 1e-4, ("dist_adjoint_2d", grid, rel_a)
         print("PENCIL_2X2_OK")
         """
     ) % (os.path.join(os.path.dirname(__file__), "..", "src"),)

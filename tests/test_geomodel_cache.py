@@ -7,7 +7,8 @@ Covers this PR's contract:
     deterministic host prelift is recomputed, never its value);
   * the property holds at BOTH cache levels: ``prelift`` (encoder-only)
     and ``deep`` (the block-input split serving cached first-block
-    kept-mode spectra/contribution through ``fno_forward_deep_split``);
+    kept-mode spectra/contribution through the ``"deep"`` prefix of
+    ``forward_and_specs``);
   * the runner's device table of static rows: served outputs equal
     uncached serving bit for bit across bucket sizes, admission orders,
     slot reuse and a geomodel pushed out of the table and served again;
@@ -16,7 +17,7 @@ Covers this PR's contract:
     shared across runners still hits and holds host arrays only;
   * the split forward (cached static prelift + dynamic lift) matches the
     fused ``fno_forward`` to float tolerance, and so does the deep split
-    (``spectral_prelift`` + ``fno_forward_deep_split``);
+    (``spectral_prelift`` + the ``"deep"`` prefix);
   * scheduler dedup: identical in-flight requests ride one slot and every
     follower gets the primary's outputs at retirement;
   * LRU eviction honors the byte budget, strips the DEEP levels of the
@@ -36,8 +37,8 @@ import pytest
 from hypothesis import given, settings, strategies as st
 
 from repro.core import (
-    FNOConfig, encoder_prelift, fno_forward, fno_forward_deep_split,
-    init_params, spectral_prelift,
+    FNOConfig, encoder_prelift, fno_forward, forward_and_specs, init_params,
+    spectral_prelift,
 )
 from repro.core.partition import make_mesh
 from repro.data.loader import Normalizer
@@ -261,32 +262,44 @@ def test_cache_hit_rate_counts_requests_and_rollout_steps():
     assert s["hit_rate"] == pytest.approx(1 - 1 / (n * steps))
 
 
+def _prefix_forward(prefix):
+    fwd, _, _ = forward_and_specs(make_mesh((1,), ("data",)), CFG,
+                                  n_static=N_STATIC, prefix=prefix)
+    return fwd
+
+
 def test_split_forward_matches_fused_to_tolerance():
     """The split (prelift + dynamic lift) path equals the fused single-
-    encoder forward up to float summation order."""
+    encoder forward up to float summation order: called directly, and
+    served by the runners of both cache levels."""
     fwd = jax.jit(lambda p, x: fno_forward(p, x, CFG))
-    for i in range(4):
-        req = _scenario(i, i % 3)
-        done, _ = _serve(RUNNER, [req], 1)
-        xe = RUNNER.x_normalizer.encode(np.asarray(req.x, np.float32)[None])
-        expected = RUNNER.y_normalizer.decode(np.asarray(fwd(PARAMS, xe)))[0]
-        np.testing.assert_allclose(req.prediction, expected, rtol=1e-4, atol=1e-5)
+    x = np.random.default_rng(12).normal(
+        size=(2, CFG.in_channels) + CFG.grid).astype(np.float32)
+    pre_s = encoder_prelift(PARAMS, x[:, :N_STATIC], CFG, slice(0, N_STATIC))
+    got = _prefix_forward("prelift")(PARAMS, pre_s, x[:, N_STATIC:])
+    np.testing.assert_allclose(
+        np.asarray(got), np.asarray(fwd(PARAMS, x)), rtol=1e-4, atol=1e-5)
+    for runner in (RUNNER, RUNNER_PRELIFT):
+        for i in range(4):
+            req = _scenario(i, i % 3)
+            done, _ = _serve(runner, [req], 1)
+            xe = RUNNER.x_normalizer.encode(np.asarray(req.x, np.float32)[None])
+            expected = RUNNER.y_normalizer.decode(np.asarray(fwd(PARAMS, xe)))[0]
+            np.testing.assert_allclose(req.prediction, expected, rtol=1e-4, atol=1e-5)
 
 
 def test_deep_split_forward_matches_fused_to_tolerance():
     """The block-input split — cached first-block static kept-mode
     contribution (``spectral_prelift``) summed into the dynamic remainder's
-    pre-activation (``fno_forward_deep_split``) — equals the fused forward
-    up to float summation order."""
+    pre-activation (the ``"deep"`` prefix) — equals the fused forward up to
+    float summation order."""
     rng = np.random.default_rng(11)
     x = rng.normal(size=(2, CFG.in_channels) + CFG.grid).astype(np.float32)
     pre_s = encoder_prelift(PARAMS, x[:, :N_STATIC], CFG, slice(0, N_STATIC))
     spectra, contrib = spectral_prelift(PARAMS, pre_s, CFG)
     assert spectra.shape == (2, CFG.width) + CFG.mode_shape
     assert contrib.shape == (2, CFG.width) + CFG.mode_shape
-    got = fno_forward_deep_split(
-        PARAMS, contrib, pre_s, x[:, N_STATIC:], CFG, N_STATIC
-    )
+    got = _prefix_forward("deep")(PARAMS, contrib, pre_s, x[:, N_STATIC:])
     want = fno_forward(PARAMS, x, CFG)
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(want), rtol=1e-4, atol=1e-5

@@ -125,13 +125,15 @@ def test_one_chip_serving_step_compiles(topo, compiled_kernels):
     bucket, on the fused Pallas path: it holds the kernel and fits the
     chip with headroom."""
     from repro.core import init_params
-    from repro.core.fno import deep_split_forward_and_specs, params_with_planes
+    from repro.core.fno import contrib_spec, forward_and_specs, params_with_planes
 
     cfg, n_static, bucket = _cfg(True), 1, 2
     mesh = Mesh(np.array(topo.devices[:1]), ("data",))
-    fwd, x_spec, c_spec, p_specs = deep_split_forward_and_specs(
-        mesh, cfg, n_static, dp_axes=("data",), model_axis=None, planes=True
+    fwd, x_spec, p_specs = forward_and_specs(
+        mesh, cfg, dp_axes=("data",), model_axis=None, planes=True,
+        n_static=n_static, prefix="deep",
     )
+    c_spec = contrib_spec(("data",), None)
     params = jax.eval_shape(
         lambda: params_with_planes(init_params(jax.random.PRNGKey(0), cfg))
     )
@@ -210,16 +212,18 @@ def test_pencil_serving_step_compiles(topo, compiled_kernels):
     all-to-alls run under the ``a2a`` scope, its fused kernels under
     ``mix``, and each chip's peak fits with headroom."""
     from repro.core import init_params
-    from repro.core.fno import deep_split_forward_and_specs, params_with_planes
+    from repro.core.fno import contrib_spec, forward_and_specs, params_with_planes
 
     cfg = dataclasses.replace(
         fno_with_overrides("fno-sleipner", PENCIL_2X2_OVERRIDES), use_pallas=True
     )
     n_static, bucket = 1, 2
     mesh = Mesh(np.array(topo.devices).reshape(1, 2, 2), ("data", "mx", "my"))
-    fwd, x_spec, c_spec, p_specs = deep_split_forward_and_specs(
-        mesh, cfg, n_static, dp_axes=("data",), model_axis=("mx", "my"), planes=True
+    fwd, x_spec, p_specs = forward_and_specs(
+        mesh, cfg, dp_axes=("data",), model_axis=("mx", "my"), planes=True,
+        n_static=n_static, prefix="deep",
     )
+    c_spec = contrib_spec(("data",), ("mx", "my"))
     params = jax.eval_shape(
         lambda: params_with_planes(init_params(jax.random.PRNGKey(0), cfg))
     )

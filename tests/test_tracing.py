@@ -21,9 +21,7 @@ import pytest
 
 from repro.common import tracing
 from repro.core import FNOConfig, init_params
-from repro.core.fno import (
-    deep_split_forward_and_specs, forward_and_specs, mse_loss, params_with_planes,
-)
+from repro.core.fno import forward_and_specs, mse_loss, params_with_planes
 from repro.core.partition import make_mesh
 from repro.serve import FNORunner, ScenarioRequest, Scheduler
 from repro.train.optimizer import AdamWConfig, init_opt_state
@@ -202,13 +200,16 @@ def under(op_name, *scopes):
 
 def _compiled(program):
     # three blocks: the deep split scans the two after its first
+    deep = {"deep_split": (None, (1,), ("data",)),
+            "pencil_deep": (("mx", "my"), (1, 1, 1), ("data", "mx", "my"))}
     cfg = dataclasses.replace(CFG, grid=(8, 8, 8, 4), modes=(2, 2, 2, 2), width=4,
-                              n_blocks=3, use_pallas=program == "deep_split")
+                              n_blocks=3, use_pallas=program in deep)
     params = init_params(jax.random.PRNGKey(0), cfg)
     x = jnp.ones((2, cfg.in_channels) + cfg.grid)
-    if program == "deep_split":
-        fwd, *_ = deep_split_forward_and_specs(make_mesh((1,), ("data",)), cfg, 1,
-                                               planes=True)
+    if program in deep:
+        model_axis, shape, axes = deep[program]
+        fwd, *_ = forward_and_specs(make_mesh(shape, axes), cfg, model_axis=model_axis,
+                                    planes=True, n_static=1, prefix="deep")
         ck = jnp.ones((2, cfg.width) + cfg.mode_shape, jnp.complex64)
         pre = jnp.ones((2, cfg.width) + cfg.grid)
         return jax.jit(fwd).lower(params_with_planes(params), ck, pre, x[:, 1:]).compile()
@@ -228,7 +229,8 @@ def _compiled(program):
     return jax.jit(step).lower(params, init_opt_state(params), batch).compile()
 
 
-@pytest.mark.parametrize("program", ["forward", "dist_forward", "deep_split", "train_step"])
+@pytest.mark.parametrize(
+    "program", ["forward", "dist_forward", "deep_split", "pencil_deep", "train_step"])
 def test_every_layer_op_is_scoped(program):
     ops = OP.findall(_compiled(program).as_text())
     assert ops
